@@ -267,5 +267,8 @@ if __name__ == "__main__":
     )
     ap.add_argument("--out", default=None, help="artifact dir, e.g. experiments/paper")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for r in run(out_dir=args.out, quick=not args.full, with_faults=args.faults):
         print(r)

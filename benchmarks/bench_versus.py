@@ -133,5 +133,8 @@ if __name__ == "__main__":
     ap.add_argument("--quick", action="store_true", help="CI smoke scale")
     ap.add_argument("--out", default=None, help="artifact dir, e.g. experiments/paper")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for r in run(out_dir=args.out, quick=not args.full):
         print(r)

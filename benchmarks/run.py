@@ -15,6 +15,7 @@ from benchmarks import bench_amg, bench_bounds, bench_exec, bench_kernels, bench
 from benchmarks import bench_mcl, bench_partition, bench_plan_build, bench_select
 from benchmarks import bench_serve, bench_tab2, bench_versus, roofline
 from benchmarks.common import csv_lines
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "tab2": bench_tab2.run,
@@ -54,6 +55,7 @@ def main(argv=None) -> None:
     if args.quick and (args.full or args.scale == "paper"):
         ap.error("--quick conflicts with --full/--scale paper")
     scale = args.scale or ("paper" if args.full else "small")
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

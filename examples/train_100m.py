@@ -21,7 +21,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
 
-from repro import compat
 import jax.numpy as jnp
 
 from repro.configs import get_config
@@ -66,7 +65,7 @@ def main(argv=None):
     n = param_count(cfg)
     print(f"model {cfg.name}: {n/1e6:.1f}M params, uniform nll={math.log(cfg.vocab):.3f}")
     mesh = make_host_mesh()
-    compat.set_mesh(mesh)
+    jax.set_mesh(mesh)
 
     step = jax.jit(make_train_step(cfg, lr=args.lr), donate_argnums=(0, 1))
     params = init_params(cfg, jax.random.key(0))

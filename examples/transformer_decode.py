@@ -16,7 +16,6 @@ from functools import partial
 
 import jax
 
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -41,7 +40,7 @@ def main(argv=None):
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_host_mesh(model=args.model_parallel)
-    compat.set_mesh(mesh)
+    jax.set_mesh(mesh)
     params_sh = param_shardings(cfg, mesh)
     params = jax.jit(partial(init_params, cfg), out_shardings=params_sh)(
         jax.random.key(args.seed)

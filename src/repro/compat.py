@@ -1,10 +1,7 @@
-"""jax version-compatibility shims.
+"""The two jax calls every call site makes with the same fixed arguments.
 
-The repo is written against the modern jax API surface (``jax.shard_map``,
-``jax.set_mesh``, ``AxisType`` meshes).  Containers pin older jax (0.4.x)
-where those either live under ``jax.experimental`` or do not exist; every
-call site routes through this module so the rest of the codebase sees one
-API regardless of the installed version.
+Written against the installed jax (see ``pyproject.toml``); there are no
+version branches.
 """
 from __future__ import annotations
 
@@ -12,70 +9,15 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (new) or ``jax.experimental.shard_map`` (0.4.x).
-
-    Replication checking is disabled in both spellings (``check_vma`` /
-    ``check_rep``): the SpGEMM executors return per-device shards on purpose.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` with replication checking off (``check_vma``): the
+    SpGEMM executors return per-device shards on purpose."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the concept exists."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh(mesh):
-    """Install ``mesh`` as the ambient mesh.
-
-    New jax has ``jax.set_mesh``; on 0.4.x the equivalent process-scoped
-    state is the legacy ``Mesh`` context manager, entered and deliberately
-    never exited (callers treat the ambient mesh as process-global).
-    """
-    if hasattr(jax, "set_mesh"):
-        jax.set_mesh(mesh)
-        return mesh
-    mesh.__enter__()
-    return mesh
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` (new); a counting psum on 0.4.x."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new) / ``pltpu.TPUCompilerParams`` (0.4.x)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-def get_abstract_mesh():
-    """The ambient mesh, or ``None`` when none is installed.
-
-    Returns the abstract mesh on new jax and the physical mesh from the
-    legacy context on 0.4.x — both expose ``axis_names`` and a name-keyed
-    ``shape`` mapping, which is all the call sites use.
-    """
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src.mesh import thread_resources
-
-    mesh = thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
+    """``jax.make_mesh`` with Auto axis types (jax's default is Explicit)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )

@@ -329,7 +329,7 @@ def _make_contractor(nb: int, mb: int, pb: int, nbb: int, pbb: int):
         # exact coarse weights: group fine vertices by coarse id with a
         # vertex-sized packed sort (in-round cw is conservative, not exact,
         # when coarse nets carry duplicate pins); the driver guarantees
-        # nbb * nb fits int32 (x64 stays off — compat.py contract)
+        # nbb * nb fits int32 (x64 stays off)
         iota = jnp.arange(nb, dtype=jnp.int32)
         cmap = jnp.where(iota < n_real, rank[labels], nbb - 1)
         kv = cmap * nb + iota

@@ -655,11 +655,10 @@ def _partition_device(
 ) -> tuple[np.ndarray, dict]:
     """Device-engine dispatcher.  ``coarsen="auto"``/``"device"`` runs the
     fully device-resident V-cycle (``core/coarsen_device.py``); ``"host"``
-    keeps the PR-6 host-scipy descend.  Device-coarsening import or runtime
-    failure degrades to host coarsening with a once-per-process warning —
-    the same contract as the engine-level jax fallback."""
+    keeps the PR-6 host-scipy descend.  A missing ``coarsen_device`` module
+    degrades to host coarsening with a once-per-process warning; a failure
+    of the device kernels raises."""
     if coarsen != "host":
-        cd = None
         try:
             cd = importlib.import_module("repro.core.coarsen_device")
         except ImportError:
@@ -668,15 +667,8 @@ def _partition_device(
                 "device coarsening unavailable; falling back to host "
                 "coarsening for engine='device'",
             )
-        if cd is not None:
-            try:
-                return _partition_device_resident(hg, p, part_cap, seed, rd, cd)
-            except Exception as exc:
-                _warn_fallback(
-                    "coarsen_runtime",
-                    f"device coarsening failed ({exc!r}); falling back to "
-                    "host coarsening for engine='device'",
-                )
+        else:
+            return _partition_device_resident(hg, p, part_cap, seed, rd, cd)
     return _partition_device_hostcoarsen(hg, p, part_cap, seed, rd)
 
 
@@ -873,12 +865,12 @@ def partition(
     (``core/refine_device.py``) run as jitted kernels per level, with only
     the final labels crossing back for the host polish.  ``coarsen``
     selects the descend: ``"auto"``/``"device"`` use the device kernels
-    (degrading to host coarsening with a warning when unavailable),
-    ``"host"`` forces the PR-6 host-scipy V-cycle.  Sizes at or below
-    ``DEVICE_MIN_VERTICES`` use the flat quality path unchanged, and a
-    missing (or failing) jax degrades to ``engine="flat"`` with a
-    once-per-process warning.  Device results carry a ``phases`` dict
-    (coarsen / refine / polish seconds).
+    (degrading to host coarsening with a warning when the module is
+    missing), ``"host"`` forces the PR-6 host-scipy V-cycle.  Sizes at or
+    below ``DEVICE_MIN_VERTICES`` use the flat quality path unchanged, and a
+    missing jax degrades to ``engine="flat"`` with a once-per-process
+    warning.  A device engine that fails at run time raises.  Device
+    results carry a ``phases`` dict (coarsen / refine / polish seconds).
 
     ``warm_start``: previous labels aligned to this hypergraph's vertices
     (entries outside ``[0, p)`` = unmapped after drift).  When reuse is
@@ -919,23 +911,11 @@ def partition(
         if rd is not None:
             total = float(hg.w_comp.sum())
             part_cap = max((1 + eps) * total / p, float(hg.w_comp.max()))
-            try:
-                parts, phases = _partition_device(
-                    hg, p, part_cap, seed, rd, coarsen
-                )
-            except Exception as exc:
-                # device-runtime failure (OOM, kernel error): the host flat
-                # engine is the authoritative fallback, not a hard stop
-                _warn_fallback(
-                    "runtime",
-                    f"engine='device' failed ({exc!r}); "
-                    "falling back to engine='flat'",
-                )
-            else:
-                conn = evaluate(hg, parts, p).connectivity
-                return PartitionResult(
-                    parts=parts, p=p, connectivity=conn, phases=phases
-                )
+            # a device failure (OOM, kernel error) raises: resilience is the
+            # session's FaultPolicy, which records what it downgrades
+            parts, phases = _partition_device(hg, p, part_cap, seed, rd, coarsen)
+            conn = evaluate(hg, parts, p).connectivity
+            return PartitionResult(parts=parts, p=p, connectivity=conn, phases=phases)
         engine = "flat"
     rng = np.random.default_rng(seed)
     parts = np.zeros(hg.n_vertices, dtype=np.int64)

@@ -56,14 +56,17 @@ from repro.distributed.summa import _lower_summa, _summa_runner, summa_mesh_shap
 @dataclasses.dataclass(frozen=True)
 class RunnerSetup:
     """What the compile-once runtime needs to AOT-compile one executor:
-    a jit-compatible ``run(a_values, b_values) -> c_shards`` closure (route
-    tables and scatter indices baked in as constants), the value shapes it
-    was built for, and the dense shape ``unpack`` recovers."""
+    a jit-compatible ``run(a_values, b_values, *tables) -> c_shards``
+    function, the value shapes it was built for, the dense shape ``unpack``
+    recovers, and ``tables``: the plan's integer index arrays (scatter
+    indices, routes, pair lists) as host arrays.  The runtime uploads the
+    tables once and passes them as device arguments on every call."""
 
     run: Callable
     a_shape: tuple[int, ...]
     b_shape: tuple[int, ...]
     out_shape: tuple[int, int]
+    tables: tuple = ()
 
 
 def vmap_batched_runner(make_runner: Callable) -> Callable:
@@ -84,10 +87,12 @@ def vmap_batched_runner(make_runner: Callable) -> Callable:
 
         setup = make_runner(plan, a_structure, b_structure, mesh, **kwargs)
         return RunnerSetup(
-            run=jax.vmap(setup.run),
+            # values map over the batch axis; the tables are shared
+            run=jax.vmap(setup.run, in_axes=(0, 0) + (None,) * len(setup.tables)),
             a_shape=(batch, *setup.a_shape),
             b_shape=(batch, *setup.b_shape),
             out_shape=setup.out_shape,
+            tables=setup.tables,
         )
 
     return make_batched
@@ -187,16 +192,15 @@ def _rowwise_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backe
     bdev, bslot = owner_slot(plan.local_ids["b_row"], K)
     I_max = plan.local_ids["a_row"].shape[1]
     K_max = plan.local_ids["b_row"].shape[1]
-    a_idx = tuple(jnp.asarray(v) for v in (rdev[ar], rslot[ar], ac))
-    b_idx = tuple(jnp.asarray(v) for v in (bdev[br], bslot[br], bc))
-    step = _exec.make_rowwise_step(plan, mesh, K, J, axis=axis)
+    step, step_tables = _exec.make_rowwise_step(plan, mesh, K, J, axis=axis)
 
-    def run(a_values, b_values):
-        a_local = jnp.zeros((p, I_max, K), dtype).at[a_idx].set(a_values)
-        b_local = jnp.zeros((p, K_max, J), dtype).at[b_idx].set(b_values)
-        return step(a_local, b_local)
+    def run(a_values, b_values, a_d, a_s, a_c, b_d, b_s, b_c, *tables):
+        a_local = jnp.zeros((p, I_max, K), dtype).at[a_d, a_s, a_c].set(a_values)
+        b_local = jnp.zeros((p, K_max, J), dtype).at[b_d, b_s, b_c].set(b_values)
+        return step(a_local, b_local, *tables)
 
-    return RunnerSetup(run, (a_structure.nnz,), (b_structure.nnz,), (I, J))
+    tables = (rdev[ar], rslot[ar], ac, bdev[br], bslot[br], bc, *step_tables)
+    return RunnerSetup(run, (a_structure.nnz,), (b_structure.nnz,), (I, J), tables)
 
 
 def _outer_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend, axis, axes):
@@ -213,26 +217,26 @@ def _outer_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend
     br, bc = b_structure.coo()
     kdev, kslot = owner_slot(plan.local_ids["k"], K)
     K_max = plan.local_ids["k"].shape[1]
-    a_idx = tuple(jnp.asarray(v) for v in (kdev[ac], ar, kslot[ac]))
-    b_idx = tuple(jnp.asarray(v) for v in (kdev[br], kslot[br], bc))
-    step = _exec.make_outer_step(plan, mesh, I, J, axis=axis)
+    step, step_tables = _exec.make_outer_step(plan, mesh, I, J, axis=axis)
 
-    def run(a_values, b_values):
-        a_cols = jnp.zeros((p, I, K_max), dtype).at[a_idx].set(a_values)
-        b_rows = jnp.zeros((p, K_max, J), dtype).at[b_idx].set(b_values)
-        return step(a_cols, b_rows)
+    def run(a_values, b_values, a_d, a_r, a_s, b_d, b_s, b_c, *tables):
+        a_cols = jnp.zeros((p, I, K_max), dtype).at[a_d, a_r, a_s].set(a_values)
+        b_rows = jnp.zeros((p, K_max, J), dtype).at[b_d, b_s, b_c].set(b_values)
+        return step(a_cols, b_rows, *tables)
 
-    return RunnerSetup(run, (a_structure.nnz,), (b_structure.nnz,), (I, J))
+    tables = (kdev[ac], ar, kslot[ac], kdev[br], kslot[br], bc, *step_tables)
+    return RunnerSetup(run, (a_structure.nnz,), (b_structure.nnz,), (I, J), tables)
 
 
-def _fine_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend, axis, axes):
+def owned_nz_setup(
+    plan, a_structure, b_structure, step, step_tables, *, dtype, item_shape=(), out_shape
+) -> RunnerSetup:
+    """Runner for executors whose operands live in owned nonzero slots
+    (fine, monoA, monoB, monoC, summa2d): scatter the value vectors into
+    device-major ``(p, N_max, *item_shape)`` tables, then run ``step``."""
     import jax.numpy as jnp
 
-    from repro.distributed import spgemm_exec as _exec
-
     p = plan.p
-    I, _ = a_structure.shape
-    _, J = b_structure.shape
     nA, nB = a_structure.nnz, b_structure.nnz
     if nA != len(plan.a_part) or nB != len(plan.b_part):
         raise ValueError("plan was built for a different nonzero structure")
@@ -240,16 +244,29 @@ def _fine_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend,
     bdev, bslot = owner_slot(plan.local_ids["b_nz"], nB)
     N_a = plan.local_ids["a_nz"].shape[1]
     N_b = plan.local_ids["b_nz"].shape[1]
-    a_idx = (jnp.asarray(adev), jnp.asarray(aslot))
-    b_idx = (jnp.asarray(bdev), jnp.asarray(bslot))
-    step = _exec.make_fine_step(plan, mesh, axis=axis)
 
-    def run(a_values, b_values):
-        a_own = jnp.zeros((p, N_a), dtype).at[a_idx].set(a_values)
-        b_own = jnp.zeros((p, N_b), dtype).at[b_idx].set(b_values)
-        return step(a_own, b_own)
+    def run(a_values, b_values, a_d, a_s, b_d, b_s, *tables):
+        a_own = jnp.zeros((p, N_a, *item_shape), dtype).at[a_d, a_s].set(a_values)
+        b_own = jnp.zeros((p, N_b, *item_shape), dtype).at[b_d, b_s].set(b_values)
+        return step(a_own, b_own, *tables)
 
-    return RunnerSetup(run, (nA,), (nB,), (I, J))
+    return RunnerSetup(
+        run,
+        (nA, *item_shape),
+        (nB, *item_shape),
+        out_shape,
+        (adev, aslot, bdev, bslot, *step_tables),
+    )
+
+
+def _fine_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend, axis, axes):
+    from repro.distributed import spgemm_exec as _exec
+
+    step, tables = _exec.make_fine_step(plan, mesh, axis=axis)
+    out_shape = (a_structure.shape[0], b_structure.shape[1])
+    return owned_nz_setup(
+        plan, a_structure, b_structure, step, tables, dtype=dtype, out_shape=out_shape
+    )
 
 
 def _columnwise_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend, axis, axes):
@@ -257,8 +274,6 @@ def _columnwise_runner(plan, a_structure, b_structure, mesh, *, dtype, block, ba
     # C^T = B^T A^T instance, so the inner runner sees A' = B^T, B' = A^T
     # and produces C^T shards; values arrive in the *original* CSR orders
     # and are permuted into the transposed (col-major) orders on device
-    import jax.numpy as jnp
-
     a_t = b_structure.transpose()
     b_t = a_structure.transpose()
     inner = _rowwise_runner(
@@ -267,46 +282,32 @@ def _columnwise_runner(plan, a_structure, b_structure, mesh, *, dtype, block, ba
     )
     ar, ac = a_structure.coo()
     br, bc = b_structure.coo()
-    # CSR order of X^T enumerates X's nonzeros sorted by (col, row)
-    perm_a = jnp.asarray(np.lexsort((ar, ac)))
-    perm_b = jnp.asarray(np.lexsort((br, bc)))
 
-    def run(a_values, b_values):
-        return inner.run(b_values[perm_b], a_values[perm_a])
+    def run(a_values, b_values, perm_a, perm_b, *tables):
+        return inner.run(b_values[perm_b], a_values[perm_a], *tables)
 
     I, _ = a_structure.shape
     _, J = b_structure.shape
-    return RunnerSetup(run, (a_structure.nnz,), (b_structure.nnz,), (I, J))
+    # CSR order of X^T enumerates X's nonzeros sorted by (col, row)
+    tables = (np.lexsort((ar, ac)), np.lexsort((br, bc)), *inner.tables)
+    return RunnerSetup(run, (a_structure.nnz,), (b_structure.nnz,), (I, J), tables)
 
 
 def _monoC_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend, axis, axes):
     # a_structure / b_structure are the BLOCK structures here; values are
     # (nnz, block, block) arrays in block CSR (= to_bsr) order
-    import jax.numpy as jnp
-
     from repro.distributed import spgemm_exec as _exec
 
-    p = plan.p
-    I, _ = a_structure.shape
-    _, J = b_structure.shape
-    nA, nB = a_structure.nnz, b_structure.nnz
-    if nA != len(plan.a_part) or nB != len(plan.b_part):
-        raise ValueError("plan was built for a different block structure")
-    adev, aslot = owner_slot(plan.local_ids["a_nz"], nA)
-    bdev, bslot = owner_slot(plan.local_ids["b_nz"], nB)
-    N_a = plan.local_ids["a_nz"].shape[1]
-    N_b = plan.local_ids["b_nz"].shape[1]
-    a_idx = (jnp.asarray(adev), jnp.asarray(aslot))
-    b_idx = (jnp.asarray(bdev), jnp.asarray(bslot))
-    step = _exec.make_monoC_step(plan, mesh, block=block, backend=backend, axes=axes)
-
-    def run(a_values, b_values):
-        a_own = jnp.zeros((p, N_a, block, block), dtype).at[a_idx].set(a_values)
-        b_own = jnp.zeros((p, N_b, block, block), dtype).at[b_idx].set(b_values)
-        return step(a_own, b_own)
-
-    return RunnerSetup(
-        run, (nA, block, block), (nB, block, block), (I * block, J * block)
+    step, tables = _exec.make_monoC_step(plan, mesh, block=block, backend=backend, axes=axes)
+    return owned_nz_setup(
+        plan,
+        a_structure,
+        b_structure,
+        step,
+        tables,
+        dtype=dtype,
+        item_shape=(block, block),
+        out_shape=(a_structure.shape[0] * block, b_structure.shape[1] * block),
     )
 
 
@@ -526,9 +527,11 @@ MODEL_SPECS: dict[str, ModelSpec] = {
         axis_names=("x", "y"),
         pack_values=_values_blocked,
         needs_c_structure=True,
-        # scalar instances (block=1) through the BSR kernel pay interpret-mode
-        # overhead on CPU for no reuse; the dense XLA fallback is the right
-        # local-compute default until a caller opts into Pallas explicitly
+        # the front door plans scalar instances (block=1): the BSR kernel
+        # would run one grid step per multiplication on 1x1 tiles, each
+        # padded to a 128-lane row (512 B per scalar operand on TPU), while
+        # the XLA gather/einsum/segment-add path keeps scalars as flat
+        # vectors; backend="pallas" stays available per compile()
         compile_defaults={"backend": "xla"},
         measured="exact",
         notes="C nonzero lives on one device; 2D mesh, BSR local compute",
@@ -545,8 +548,8 @@ MODEL_SPECS: dict[str, ModelSpec] = {
         axis_names=("x", "y"),
         pack_values=_values_blocked,
         needs_c_structure=True,
-        # same rationale as monoC: scalar blocks through the BSR kernel pay
-        # interpret-mode overhead on CPU; dense XLA fallback by default
+        # same reason as monoC: scalar (1x1) blocks, one kernel grid step
+        # and one lane-padded tile per multiplication
         compile_defaults={"backend": "xla"},
         measured="exact",
         in_auto=False,

@@ -15,8 +15,10 @@ so nothing caches).
 
 - host packing collapses to one vectorized owner/slot scatter-spec (the
   ``np.nonzero(local_ids >= 0)`` idiom), computed at construction;
-- route tables, pair lists and scatter indices are uploaded once and baked
-  into the program as compile-time constants;
+- route tables, pair lists and scatter indices are uploaded to the devices
+  once, with the shardings the compiled program asks for, and passed as
+  arguments on every call (baked in as constants, they made compile time
+  and the executable grow with the plan);
 - the whole executor (value scatter -> expand -> local compute -> reduce)
   is AOT-compiled via ``jax.jit(...).lower().compile()`` with the value
   buffers donated, so ``__call__(a_values, b_values)`` does zero host
@@ -207,10 +209,12 @@ class CompiledSpGEMM:
         self._I, self._J = setup.out_shape
         self._a_shape, self._b_shape = setup.a_shape, setup.b_shape
         run = setup.run
+        # index tables ride as int32 (jax runs without x64)
+        tables = [np.asarray(t, dtype=np.int32) for t in setup.tables]
 
-        def traced(a_values, b_values):
+        def traced(a_values, b_values, *tables):
             _mark_trace()
-            return run(a_values, b_values)
+            return run(a_values, b_values, *tables)
 
         with warnings.catch_warnings():
             # donation is best-effort: backends without it (CPU) warn per
@@ -223,9 +227,15 @@ class CompiledSpGEMM:
                 .lower(
                     jax.ShapeDtypeStruct(setup.a_shape, dt),
                     jax.ShapeDtypeStruct(setup.b_shape, dt),
+                    *(jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tables),
                 )
                 .compile()
             )
+        # upload once, placed as the program wants them: no per-call transfer
+        in_shardings = self._compiled.input_shardings[0][2:]
+        self._tables = tuple(
+            jax.device_put(t, sh) for t, sh in zip(tables, in_shardings)
+        )
 
     def _coerce(self, x, shape, name: str):
         if isinstance(x, jax.Array):
@@ -250,7 +260,7 @@ class CompiledSpGEMM:
         faults.fire("execute")
         a = self._coerce(a_values, self._a_shape, "A")
         b = self._coerce(b_values, self._b_shape, "B")
-        return self._compiled(a, b)
+        return self._compiled(a, b, *self._tables)
 
     def unpack(self, c_local) -> np.ndarray:
         """Scatter device-major C shards back to a dense (I, J) array (padded

@@ -28,9 +28,11 @@ Every sparsity-dependent executor consumes an ``ExecutionPlan``
 (``plan_ir``): ownership maps + padded routing tables + local work lists.
 
 Structure-time vs value-time split (DESIGN.md §8): each executor's math
-lives in a ``make_*_step`` builder that closes over the plan's routing
-tables and work lists as compile-time constants and returns a jit-compatible
-function over device-major *packed* operand arrays.  The dense entry points
+lives in a ``make_*_step`` builder that returns a jit-compatible function
+over device-major *packed* operand arrays together with the plan's routing
+tables and work lists, which the function takes as arguments (the runtime
+uploads them to the devices once; baked in as constants they made compile
+time grow with the plan).  The dense entry points
 below are thin wrappers over ``repro.distributed.runtime.compile_spgemm``,
 which scatters nonzero value vectors into the packed layout *inside* the
 compiled program and AOT-compiles the whole executor once per
@@ -63,13 +65,12 @@ def _take0(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
 def make_rowwise_step(plan: RowwisePlan, mesh: Mesh, K: int, J: int, axis: str = "x"):
     """Jit-compatible row-wise executor core.
 
-    Returns ``fn(a_local, b_local) -> c_local`` over device-major packed row
-    tables (``a_local``: (p, I_max, K); ``b_local``: (p, K_max, J)); the
-    plan's route tables enter as compile-time constants, uploaded once.
+    Returns ``(fn, tables)``: ``fn(a_local, b_local, *tables) -> c_local``
+    over device-major packed row tables (``a_local``: (p, I_max, K);
+    ``b_local``: (p, K_max, J)); ``tables`` are the plan's route tables
+    (send_idx, recv_key: (p, p, T); local_b_rows: (p, K_max)).
     """
-    send_idx = jnp.asarray(plan.send_idx)  # (p, p, T)
-    recv_key = jnp.asarray(plan.recv_key)  # (p, p, T)
-    local_b_rows = jnp.asarray(plan.local_b_rows)  # (p, K_max)
+    tables = (plan.send_idx, plan.recv_key, plan.local_b_rows)
 
     def step(a_blk, b_blk, send_idx_blk, recv_key_all, my_b_rows):
         # a_blk: (1, I_max, K); b_blk: (1, K_max, J) — this device's shard
@@ -107,11 +108,7 @@ def make_rowwise_step(plan: RowwisePlan, mesh: Mesh, K: int, J: int, axis: str =
         in_specs=(P(axis), P(axis), P(axis), P(), P(axis)),
         out_specs=P(axis),
     )
-
-    def fn(a_local, b_local):
-        return shard(a_local, b_local, send_idx, recv_key, local_b_rows)
-
-    return fn
+    return shard, tables
 
 
 def _dense_call_1d(plan, a_dense, b_dense, mesh: Mesh, axis: str) -> jnp.ndarray:
@@ -166,8 +163,9 @@ def unpack_rowwise_result(c_local: jnp.ndarray, plan: RowwisePlan, I: int) -> np
 def make_outer_step(plan: OuterPlan, mesh: Mesh, I: int, J: int, axis: str = "x"):
     """Jit-compatible outer-product executor core.
 
-    Returns ``fn(a_cols, b_rows) -> c_shards`` over device-major packed
-    operand tables (``a_cols``: (p, I, K_max); ``b_rows``: (p, K_max, J)).
+    Returns ``(fn, ())``: ``fn(a_cols, b_rows) -> c_shards`` over
+    device-major packed operand tables (``a_cols``: (p, I, K_max);
+    ``b_rows``: (p, K_max, J)); the plan adds no tables.
     """
     p = plan.p
     I_pad = (I + p - 1) // p * p
@@ -182,12 +180,13 @@ def make_outer_step(plan: OuterPlan, mesh: Mesh, I: int, J: int, axis: str = "x"
         )
         return mine[None]
 
-    return shard_map(
+    shard = shard_map(
         step,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=P(axis),
     )
+    return shard, ()
 
 
 def outer_product_spgemm(
@@ -257,9 +256,9 @@ def make_monoC_step(
 ):
     """Jit-compatible monochrome-C executor core.
 
-    Returns ``fn(a_own, b_own) -> c_local`` over device-major packed block
-    tables ((p, N_max, b, b)); route tables and BSR pair lists enter as
-    compile-time constants.
+    Returns ``(fn, tables)``: ``fn(a_own, b_own, *tables) -> c_local`` over
+    device-major packed block tables ((p, N_max, b, b)); ``tables`` are the
+    two expand routes' send slots and the BSR pair lists.
     """
     from repro.kernels.bsr_spgemm import bsr_spgemm_local
 
@@ -267,11 +266,13 @@ def make_monoC_step(
     route_a, route_b = plan.routes["expand_a"], plan.routes["expand_b"]
     T_a, T_b = route_a.T, route_b.T
     n_c_slots = plan.n_c_slots
-    sa = jnp.asarray(route_a.send_idx)
-    sb = jnp.asarray(route_b.send_idx)
-    pa = jnp.asarray(plan.compute["pair_a"], jnp.int32)
-    pb = jnp.asarray(plan.compute["pair_b"], jnp.int32)
-    pc = jnp.asarray(plan.compute["pair_c"], jnp.int32)
+    tables = (
+        route_a.send_idx,
+        route_b.send_idx,
+        plan.compute["pair_a"],
+        plan.compute["pair_b"],
+        plan.compute["pair_c"],
+    )
 
     def expand(own, send_idx_blk, T):
         # own: (N_max, b, b); send_idx_blk: (p, T) local slots to ship
@@ -299,11 +300,7 @@ def make_monoC_step(
         in_specs=(spec,) * 7,
         out_specs=spec,
     )
-
-    def fn(a_own, b_own):
-        return shard(a_own, b_own, sa, sb, pa, pb, pc)
-
-    return fn
+    return shard, tables
 
 
 def monoC_spgemm(
@@ -352,6 +349,22 @@ def monoC_spgemm(
     return exe(ab.blocks, bb.blocks)
 
 
+def owned_c_values(c_local: jnp.ndarray, plan) -> np.ndarray:
+    """Device-major owned-C slots -> C values in canonical CSR order.
+
+    Works for every plan whose C lives in owned slots (fine, monoA, monoB,
+    monoC, summa2d): ``plan.local_ids["c_nz"]`` names the C nonzero each
+    slot holds.  The result is ``(nnz(C),)`` for scalar plans and
+    ``(nnz(C), b, b)`` for blocked ones; nothing is densified.
+    """
+    c_np = np.asarray(c_local)
+    local_c = plan.local_ids["c_nz"]
+    dev, slot = np.nonzero(local_c >= 0)
+    out = np.empty((len(plan.ownership["c_nz"]), *c_np.shape[2:]), c_np.dtype)
+    out[local_c[dev, slot]] = c_np[dev, slot]
+    return out
+
+
 def unpack_monoC_result(
     c_local: jnp.ndarray,
     plan: MonoCPlan,
@@ -363,15 +376,12 @@ def unpack_monoC_result(
     ``c_structure`` is the block-grid structure of C (``inst.c`` of the plan
     instance); ``shape`` the padded dense shape (block-grid * block).
     """
-    c_np = np.asarray(c_local)
-    b = c_np.shape[-1]
+    vals = owned_c_values(c_local, plan)
+    b = vals.shape[-1]
     gr, gc = shape[0] // b, shape[1] // b
     crow, ccol = c_structure.coo()
-    out = np.zeros((gr, gc, b, b), dtype=c_np.dtype)
-    local_c = plan.local_ids["c_nz"]
-    dev, slot = np.nonzero(local_c >= 0)
-    gids = local_c[dev, slot]
-    out[crow[gids], ccol[gids]] = c_np[dev, slot]
+    out = np.zeros((gr, gc, b, b), dtype=vals.dtype)
+    out[crow, ccol] = vals
     return out.transpose(0, 2, 1, 3).reshape(shape)
 
 
@@ -381,9 +391,10 @@ def unpack_monoC_result(
 def make_fine_step(plan: FinePlan, mesh: Mesh, axis: str = "x"):
     """Jit-compatible fine-grained executor core (expand-expand-reduce).
 
-    Returns ``fn(a_own, b_own) -> c_local`` over device-major packed scalar
-    slot tables ((p, N_max)); all three route tables, the multiplication
-    lists and the reduce/fold maps enter as compile-time constants.
+    Returns ``(fn, tables)``: ``fn(a_own, b_own, *tables) -> c_local`` over
+    device-major packed scalar slot tables ((p, N_max)); ``tables`` are the
+    three routes' send slots, the multiplication lists and the reduce/fold
+    maps.
     """
     p = plan.p
     route_a = plan.routes["expand_a"]
@@ -392,14 +403,16 @@ def make_fine_step(plan: FinePlan, mesh: Mesh, axis: str = "x"):
     T_a, T_b, T_r = route_a.T, route_b.T, route_r.T
     R_max = plan.local_ids["c_prod"].shape[1]
     C_max = plan.local_ids["c_nz"].shape[1]
-    sa = jnp.asarray(route_a.send_idx)
-    sb = jnp.asarray(route_b.send_idx)
-    sr = jnp.asarray(route_r.send_idx)
-    pa = jnp.asarray(plan.compute["pair_a"])
-    pb = jnp.asarray(plan.compute["pair_b"])
-    pc = jnp.asarray(plan.compute["pair_c"])
-    recv_slot = jnp.asarray(plan.compute["reduce_recv_slot"])
-    prod_own = jnp.asarray(plan.compute["prod_to_owned"])
+    tables = (
+        route_a.send_idx,
+        route_b.send_idx,
+        route_r.send_idx,
+        plan.compute["pair_a"],
+        plan.compute["pair_b"],
+        plan.compute["pair_c"],
+        plan.compute["reduce_recv_slot"],
+        plan.compute["prod_to_owned"],
+    )
 
     def expand(own, send_idx_blk, T):
         # own: (N_max,); ship my cut-net scalars, receive the foreign ones
@@ -442,11 +455,7 @@ def make_fine_step(plan: FinePlan, mesh: Mesh, axis: str = "x"):
         in_specs=(P(axis),) * 8 + (P(), P(axis)),
         out_specs=P(axis),
     )
-
-    def fn(a_own, b_own):
-        return shard(a_own, b_own, sa, sb, sr, pa, pb, pc, recv_slot, prod_own)
-
-    return fn
+    return shard, tables
 
 
 def fine_spgemm(
@@ -504,11 +513,8 @@ def unpack_fine_result(
     shape: tuple[int, int],
 ) -> np.ndarray:
     """Scatter device-major owned-C slot values back to a dense array."""
-    c_np = np.asarray(c_local)
+    vals = owned_c_values(c_local, plan)
     crow, ccol = c_structure.coo()
-    out = np.zeros(shape, dtype=c_np.dtype)
-    local_c = plan.local_ids["c_nz"]
-    dev, slot = np.nonzero(local_c >= 0)
-    gids = local_c[dev, slot]
-    out[crow[gids], ccol[gids]] = c_np[dev, slot]
+    out = np.zeros(shape, dtype=vals.dtype)
+    out[crow, ccol] = vals
     return out

@@ -246,12 +246,14 @@ def make_summa_step(
 ):
     """Jit-compatible SUMMA executor core.
 
-    Returns ``fn(a_own, b_own) -> c_local`` over device-major packed block
-    tables ``(p, N_max, b, b)``.  The stage loop is unrolled in Python —
-    ``n_stages`` is a small compile-time constant (``lcm(pr, pc)``), so the
-    whole pipeline AOT-compiles to one executable and each stage is the
-    monoC expand (gather -> flattened two-axis ``all_to_all`` -> concat)
-    followed by a BSR pair-list multiply accumulated into the owned C slots.
+    Returns ``(fn, tables)``: ``fn(a_own, b_own, *tables) -> c_local`` over
+    device-major packed block tables ``(p, N_max, b, b)``; ``tables`` holds
+    each stage's two send-slot tables and three pair lists.  The stage loop
+    is unrolled in Python — ``n_stages`` is a small compile-time constant
+    (``lcm(pr, pc)``), so the whole pipeline AOT-compiles to one executable
+    and each stage is the monoC expand (gather -> flattened two-axis
+    ``all_to_all`` -> concat) followed by a BSR pair-list multiply
+    accumulated into the owned C slots.
     """
     import jax
     import jax.numpy as jnp
@@ -265,17 +267,17 @@ def make_summa_step(
     S = plan.n_stages
     n_c_slots = plan.n_c_slots
     stage_T = []
-    consts = []
+    tables = []
     for t in range(S):
         route_a = plan.routes[f"bcast_a_s{t}"]
         route_b = plan.routes[f"bcast_b_s{t}"]
         stage_T.append((route_a.T, route_b.T))
-        consts += [
-            jnp.asarray(route_a.send_idx),
-            jnp.asarray(route_b.send_idx),
-            jnp.asarray(plan.compute[f"pair_a_s{t}"], jnp.int32),
-            jnp.asarray(plan.compute[f"pair_b_s{t}"], jnp.int32),
-            jnp.asarray(plan.compute[f"pair_c_s{t}"], jnp.int32),
+        tables += [
+            route_a.send_idx,
+            route_b.send_idx,
+            plan.compute[f"pair_a_s{t}"],
+            plan.compute[f"pair_b_s{t}"],
+            plan.compute[f"pair_c_s{t}"],
         ]
 
     def expand(own, send_idx_blk, T):
@@ -307,39 +309,22 @@ def make_summa_step(
         in_specs=(spec,) * (2 + 5 * S),
         out_specs=spec,
     )
-
-    def fn(a_own, b_own):
-        return shard(a_own, b_own, *consts)
-
-    return fn
+    return shard, tuple(tables)
 
 
 def _summa_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend, axis, axes):
     """Registry runner factory (monoC value layout: ``(nnz, b, b)`` blocks
     scattered into device-major owned tables)."""
-    import jax.numpy as jnp
+    from repro.distributed.registry import owned_nz_setup
 
-    from repro.distributed.registry import RunnerSetup, owner_slot
-
-    p = plan.p
-    I, _ = a_structure.shape
-    _, J = b_structure.shape
-    nA, nB = a_structure.nnz, b_structure.nnz
-    if nA != len(plan.a_part) or nB != len(plan.b_part):
-        raise ValueError("plan was built for a different nonzero structure")
-    adev, aslot = owner_slot(plan.local_ids["a_nz"], nA)
-    bdev, bslot = owner_slot(plan.local_ids["b_nz"], nB)
-    N_a = plan.local_ids["a_nz"].shape[1]
-    N_b = plan.local_ids["b_nz"].shape[1]
-    a_idx = (jnp.asarray(adev), jnp.asarray(aslot))
-    b_idx = (jnp.asarray(bdev), jnp.asarray(bslot))
-    step = make_summa_step(plan, mesh, block=block, backend=backend, axes=axes)
-
-    def run(a_values, b_values):
-        a_own = jnp.zeros((p, N_a, block, block), dtype).at[a_idx].set(a_values)
-        b_own = jnp.zeros((p, N_b, block, block), dtype).at[b_idx].set(b_values)
-        return step(a_own, b_own)
-
-    return RunnerSetup(
-        run, (nA, block, block), (nB, block, block), (I * block, J * block)
+    step, tables = make_summa_step(plan, mesh, block=block, backend=backend, axes=axes)
+    return owned_nz_setup(
+        plan,
+        a_structure,
+        b_structure,
+        step,
+        tables,
+        dtype=dtype,
+        item_shape=(block, block),
+        out_shape=(a_structure.shape[0] * block, b_structure.shape[1] * block),
     )

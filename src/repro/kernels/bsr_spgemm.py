@@ -7,15 +7,17 @@ host-side inspector enumerates the coarse multiplication vertices — every
 C block (the monochrome-C fiber), and the kernel streams the pair list
 through the MXU, accumulating runs of pairs into one C tile.
 
-Grid: (n_pairs,).  Scalar-prefetched pair lists drive the BlockSpec index
-maps; the output tile is revisited for consecutive pairs with equal pair_c,
-with a first-visit predicate doing the init (sequential TPU grid).
-VMEM per step: 3 * b^2 * 4B (fp32 acc) -> b=256 still only 768 KiB.
+Grid: (n_pairs,) per call.  Scalar-prefetched pair lists drive the
+BlockSpec index maps; the output tile is revisited for consecutive pairs
+with equal pair_c and loaded from the running C on its first visit
+(sequential TPU grid).  The pair lists live in SMEM (1 MiB on v5e), so a
+list longer than ``PAIRS_PER_CALL`` runs as a loop of calls, each adding
+its chunk into the C the previous one returned (aliased in place).
+VMEM per step: 4 * b^2 * 4B (fp32) -> b=256 still only 1 MiB.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,23 +25,53 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.kernels import resolve_interpret
+
+#: pairs per pallas_call: three int32 lists of this length take 384 KiB of
+#: SMEM (Mosaic refuses ~87K pairs in one call on v5e)
+PAIRS_PER_CALL = 32768
 
 
-def _kernel(pa_ref, pb_ref, pc_ref, a_ref, b_ref, o_ref, *, acc_dtype):
+def _kernel(n_ref, pa_ref, pb_ref, pc_ref, a_ref, b_ref, c_ref, o_ref, *, acc_dtype):
     i = pl.program_id(0)
     first = jnp.logical_or(i == 0, pc_ref[jnp.maximum(i - 1, 0)] != pc_ref[i])
 
     @pl.when(first)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    def _load():
+        o_ref[...] = c_ref[...]
 
-    prod = jnp.dot(
-        a_ref[0].astype(acc_dtype),
-        b_ref[0].astype(acc_dtype),
-        preferred_element_type=acc_dtype,
-    )
-    o_ref[...] += prod.astype(o_ref.dtype)
+    @pl.when(i < n_ref[0])  # steps past n_ref[0] pad the last chunk
+    def _accumulate():
+        prod = jnp.dot(
+            a_ref[0].astype(acc_dtype),
+            b_ref[0].astype(acc_dtype),
+            precision=jax.lax.Precision.HIGHEST,  # fp32 products on the MXU
+            preferred_element_type=acc_dtype,
+        )
+        o_ref[...] += prod.astype(o_ref.dtype)
+
+
+def _accumulate_chunk(c, a_blocks, b_blocks, n_valid, pa, pb, pc, interpret, acc_dtype):
+    """One pallas_call: ``c`` plus the products of one chunk of pairs."""
+    bm, bk = a_blocks.shape[1], a_blocks.shape[2]
+    bn = b_blocks.shape[2]
+    return pl.pallas_call(
+        functools.partial(_kernel, acc_dtype=acc_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,  # n_valid, pair_a, pair_b, pair_c
+            grid=(pa.shape[0],),
+            in_specs=[
+                pl.BlockSpec((1, bm, bk), lambda i, n, pa, pb, pc: (pa[i], 0, 0)),
+                pl.BlockSpec((1, bk, bn), lambda i, n, pa, pb, pc: (pb[i], 0, 0)),
+                pl.BlockSpec((1, bm, bn), lambda i, n, pa, pb, pc: (pc[i], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bm, bn), lambda i, n, pa, pb, pc: (pc[i], 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct(c.shape, c.dtype),
+        input_output_aliases={6: 0},  # c (after the 4 prefetch lists, A, B)
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+    )(n_valid, pa, pb, pc, a_blocks, b_blocks, c)
 
 
 @functools.partial(
@@ -56,26 +88,30 @@ def _bsr_spgemm_jit(
     acc_dtype=jnp.float32,
 ) -> jnp.ndarray:
     n_pairs = pair_a.shape[0]
-    bm, bk = a_blocks.shape[1], a_blocks.shape[2]
-    bn = b_blocks.shape[2]
     out_dtype = jnp.promote_types(a_blocks.dtype, b_blocks.dtype)
-    kernel = functools.partial(_kernel, acc_dtype=acc_dtype)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,  # pair_a, pair_b, pair_c
-            grid=(n_pairs,),
-            in_specs=[
-                pl.BlockSpec((1, bm, bk), lambda i, pa, pb, pc: (pa[i], 0, 0)),
-                pl.BlockSpec((1, bk, bn), lambda i, pa, pb, pc: (pb[i], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, bm, bn), lambda i, pa, pb, pc: (pc[i], 0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((n_c_blocks, bm, bn), out_dtype),
-        interpret=interpret,
-        compiler_params=tpu_compiler_params(dimension_semantics=("arbitrary",)),
-    )(pair_a, pair_b, pair_c, a_blocks, b_blocks)
-    return out
+    c = jnp.zeros((n_c_blocks, a_blocks.shape[1], b_blocks.shape[2]), out_dtype)
+    if n_pairs == 0:
+        return c
+    chunk = min(n_pairs, PAIRS_PER_CALL)
+    n_chunks = -(-n_pairs // chunk)
+    pad = n_chunks * chunk - n_pairs
+
+    def chunked(x):
+        # padding repeats the last pair: the tail steps stay on the final C
+        # tile and skip their product (n_valid)
+        x = jnp.concatenate([x, jnp.broadcast_to(x[-1], (pad,))])
+        return x.reshape(n_chunks, chunk)
+
+    pa, pb, pc = chunked(pair_a), chunked(pair_b), chunked(pair_c)
+
+    def body(k, c):
+        n_valid = jnp.minimum(chunk, n_pairs - k * chunk).astype(jnp.int32)
+        return _accumulate_chunk(
+            c, a_blocks, b_blocks, n_valid.reshape(1), pa[k], pb[k], pc[k],
+            interpret, acc_dtype,
+        )
+
+    return jax.lax.fori_loop(0, n_chunks, body, c)
 
 
 def _pair_list_int32(x) -> jnp.ndarray:
@@ -97,9 +133,12 @@ def bsr_spgemm(
     pair_b: jnp.ndarray,  # (np,) int
     pair_c: jnp.ndarray,  # (np,) int sorted ascending (runs per C block)
     n_c_blocks: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
     acc_dtype=jnp.float32,
 ) -> jnp.ndarray:
+    """C blocks ``(n_c_blocks, bm, bn)``: ``C[pair_c] += A[pair_a] @ B[pair_b]``;
+    C blocks no pair reaches are zero.  ``interpret=None`` picks the mode
+    from the platform (``repro.kernels.resolve_interpret``)."""
     pair_a = _pair_list_int32(pair_a)
     pair_b = _pair_list_int32(pair_b)
     pair_c = _pair_list_int32(pair_c)
@@ -110,7 +149,7 @@ def bsr_spgemm(
         pair_b,
         pair_c,
         n_c_blocks,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         acc_dtype=acc_dtype,
     )
 
@@ -192,17 +231,6 @@ def build_pair_lists_loop(
     return pair_a, pair_b, pair_c, c_brows, c_bcols
 
 
-def _default_backend() -> str:
-    env = os.environ.get("REPRO_SPGEMM_BACKEND")
-    if env:
-        return env
-    return (
-        "interpret"
-        if os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-        else "pallas"
-    )
-
-
 def bsr_spgemm_local(
     a_blocks: jnp.ndarray,
     b_blocks: jnp.ndarray,
@@ -214,18 +242,17 @@ def bsr_spgemm_local(
 ) -> jnp.ndarray:
     """Local-compute entry point the distributed executors route through.
 
-    ``backend``: 'pallas' (compiled Mosaic, TPU), 'interpret' (Pallas
-    interpreter — correct anywhere, the CPU fallback), or 'xla' (dense
-    gather/einsum/segment-add fallback, fastest without a TPU attached).
-    Default: $REPRO_SPGEMM_BACKEND, else interpret/pallas per
-    $REPRO_PALLAS_INTERPRET like the rest of ``repro.kernels``.
+    ``backend``: 'pallas' (the compiled Mosaic kernel), 'interpret' (the
+    same kernel through the Pallas interpreter; refused on a TPU), or 'xla'
+    (the gather/einsum/segment-add reference, ``ref.bsr_spgemm_ref``).
+    ``None`` picks the kernel for the platform: 'pallas' on TPU,
+    'interpret' on CPU.
     """
-    backend = backend or _default_backend()
     if backend == "xla":
         from repro.kernels.ref import bsr_spgemm_ref
 
         return bsr_spgemm_ref(a_blocks, b_blocks, pair_a, pair_b, pair_c, n_c_blocks)
-    if backend not in ("pallas", "interpret"):
+    if backend not in (None, "pallas", "interpret"):
         raise ValueError(f"unknown SpGEMM backend {backend!r}")
     return bsr_spgemm(
         a_blocks,
@@ -234,5 +261,5 @@ def bsr_spgemm_local(
         pair_b,
         pair_c,
         n_c_blocks=n_c_blocks,
-        interpret=backend == "interpret",
+        interpret=None if backend is None else backend == "interpret",
     )

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 
 def _kernel(brows_ref, bcols_ref, a_ref, b_ref, o_ref, *, acc_dtype):
     i = pl.program_id(1)  # block index (inner grid axis)
@@ -81,7 +79,7 @@ def bsr_spmm(
         ),
         out_shape=jax.ShapeDtypeStruct((m_blocks * b_m, N), out_dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
     )(brows.astype(jnp.int32), bcols.astype(jnp.int32), blocks, dense)

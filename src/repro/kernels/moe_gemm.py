@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 
 def _kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int, acc_dtype):
     k = pl.program_id(3)
@@ -69,7 +67,7 @@ def moe_gemm(
         out_shape=jax.ShapeDtypeStruct((E, C, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((b_c, b_f), acc_dtype)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
     )(x, w)

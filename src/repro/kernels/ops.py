@@ -1,26 +1,20 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this container (CPU) the kernels execute with interpret=True; on a real
-TPU set ``REPRO_PALLAS_INTERPRET=0`` (or pass interpret=False) to run the
-compiled Mosaic kernels.  The BSR entry points also accept host-side
-``BlockSparse`` matrices and run the inspector (pair-list construction).
+``interpret=None`` (the default) runs the compiled Mosaic kernels on a TPU
+and the Pallas interpreter on the CPU (``repro.kernels.resolve_interpret``).
+The BSR entry points also accept host-side ``BlockSparse`` matrices and run
+the inspector (pair-list construction).
 """
 from __future__ import annotations
-
-import os
 
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ref
+from repro.kernels import ref, resolve_interpret
 from repro.kernels.bsr_spgemm import bsr_spgemm, build_pair_lists
 from repro.kernels.bsr_spmm import bsr_spmm
 from repro.kernels.moe_gemm import moe_gemm
 from repro.sparse.bsr import BlockSparse
-
-
-def _interpret_default() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def spmm(bsr: BlockSparse, dense: np.ndarray, interpret: bool | None = None):
@@ -43,7 +37,7 @@ def spmm(bsr: BlockSparse, dense: np.ndarray, interpret: bool | None = None):
         jnp.asarray(bcols[order]),
         jnp.asarray(dense),
         m_blocks=m_blocks,
-        interpret=_interpret_default() if interpret is None else interpret,
+        interpret=resolve_interpret(interpret),
     )
 
 
@@ -62,7 +56,7 @@ def spgemm(
         jnp.asarray(pb),
         jnp.asarray(pc),
         n_c_blocks=len(crows),
-        interpret=_interpret_default() if interpret is None else interpret,
+        interpret=interpret,
     )
     return out, crows, ccols
 
@@ -72,7 +66,7 @@ def grouped_gemm(x, w, interpret: bool | None = None):
     return moe_gemm(
         jnp.asarray(x),
         jnp.asarray(w),
-        interpret=_interpret_default() if interpret is None else interpret,
+        interpret=resolve_interpret(interpret),
     )
 
 

@@ -1,6 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose ground truth)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -36,7 +37,13 @@ def bsr_spgemm_ref(
     exactly the coarsened multiplication vertices v_(IKJ) of the tiled
     SpGEMM hypergraph.
     """
-    prod = jnp.einsum("nij,njk->nik", a_blocks[pair_a], b_blocks[pair_b])
+    # HIGHEST: the TPU's default f32 matmul rounds operands to bf16
+    prod = jnp.einsum(
+        "nij,njk->nik",
+        a_blocks[pair_a],
+        b_blocks[pair_b],
+        precision=jax.lax.Precision.HIGHEST,
+    )
     out = jnp.zeros(
         (n_c_blocks, a_blocks.shape[1], b_blocks.shape[2]),
         jnp.promote_types(a_blocks.dtype, b_blocks.dtype),

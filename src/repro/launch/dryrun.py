@@ -27,7 +27,6 @@ from functools import partial
 
 import jax
 
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -205,7 +204,7 @@ def run_cell(
     try:
         import dataclasses as _dc
 
-        compat.set_mesh(mesh)  # ambient mesh: with_sharding_constraint sees it
+        jax.set_mesh(mesh)  # ambient mesh: with_sharding_constraint sees it
         donate_on = "donate" in opts
         # --- 1. full-depth compile (the deliverable): memory + success ---
         fn, args, in_sh, out_sh, don = build_cell(arch, shape, mesh, opts=opts)

@@ -377,14 +377,15 @@ def main(argv=None):
         args.n, args.requests, args.structures = 48, 24, 2
 
     from repro.api import device_count
+    from repro.launch.compile_cache import enable_compile_cache
 
     if device_count() < args.p:
-        print(
-            f"only {device_count()} device(s) visible; rerun with "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={args.p} "
-            f"(falling back to --p 1)"
+        raise SystemExit(
+            f"--p {args.p} needs {args.p} devices but {device_count()} are "
+            f"visible (on CPU: XLA_FLAGS=--xla_force_host_platform_device_count"
+            f"={args.p})"
         )
-        args.p = 1
+    enable_compile_cache()
 
     workload = _mixed_workload(
         args.n, args.density, args.structures, args.requests, args.drift, args.seed

@@ -16,7 +16,6 @@ from functools import partial
 
 import jax
 
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -56,7 +55,7 @@ def main(argv=None):
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_host_mesh(model=args.model_parallel)
-    compat.set_mesh(mesh)
+    jax.set_mesh(mesh)
     jitted, params_sh, opt_init = build_trainer(
         cfg, mesh, lr=args.lr, optimizer=args.optimizer
     )

@@ -11,7 +11,6 @@ from functools import partial
 
 import jax
 
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -237,7 +236,7 @@ def moe_layer(
         perm = jnp.asarray(np.asarray(moe.expert_placement))
         gate_idx = perm[gate_idx]
 
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     ep_ok = (
         mesh is not None
         and "model" in mesh.axis_names

@@ -9,7 +9,6 @@ from __future__ import annotations
 import numpy as np
 import jax
 
-from repro import compat
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # logical axis name -> mesh axis (None = replicated)
@@ -193,7 +192,7 @@ def param_shardings(cfg, mesh: Mesh, serve: bool = False):
 def constrain(x, *logical_axes):
     """with_sharding_constraint by logical axes; no-op outside a mesh context
     (CPU smoke tests).  Divisibility-checked against the ambient mesh."""
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return x
     spec = _fit_spec(spec_for(*logical_axes, mesh=mesh), x.shape, mesh)

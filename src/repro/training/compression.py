@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
 import jax.numpy as jnp
 
 
@@ -34,7 +33,7 @@ def compressed_psum_mean(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Inside shard_map: mean of ``x + err`` over ``axis`` using int8 wire
     format.  Returns (mean, new_error)."""
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     xe = x.astype(jnp.float32) + err
     # scales differ per participant: agree on the axis-max scale (one scalar
     # pmax) so a single int32 reduction is exact w.r.t. the shared scale.

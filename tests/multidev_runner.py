@@ -537,7 +537,7 @@ def case_moe_ep():
 
     # EP path: mesh with model axis 2 (4 experts / 2 columns), data axis 2
     mesh = compat.make_mesh((2, 2), ("data", "model"))
-    compat.set_mesh(mesh)
+    jax.set_mesh(mesh)
     try:
         from repro.models.sharding import param_shardings, batch_sharding
         psh = param_shardings(cfg, mesh)

@@ -191,19 +191,18 @@ def test_blocked_coarsen_import_falls_back_to_host_coarsening(
 def test_runtime_coarsen_failure_falls_back_to_host_coarsening(
     device_everywhere, monkeypatch
 ):
-    """A descend that dies at runtime degrades to host coarsening with one
-    warning and the identical host-coarsening result."""
+    """A descend that dies at run time raises — no silent switch to host
+    coarsening, and no warning stands in for the error."""
 
     def boom(level, cap, seed, index):
         raise RuntimeError("RESOURCE_EXHAUSTED: injected device OOM")
 
     hg = build_model(_instance(8), "rowwise")
-    want = partition(hg, 4, eps=0.10, seed=0, engine="device", coarsen="host")
     monkeypatch.setattr(coarsen_device, "coarsen_level", boom)
-    with pytest.warns(RuntimeWarning, match="host coarsening"):
-        got = partition(hg, 4, eps=0.10, seed=0, engine="device")
-    assert np.array_equal(got.parts, want.parts)
-    assert got.connectivity == want.connectivity
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        with pytest.raises(RuntimeError, match="injected device OOM"):
+            partition(hg, 4, eps=0.10, seed=0, engine="device")
 
 
 def test_bad_coarsen_value_rejected():

@@ -75,6 +75,49 @@ def test_bsr_spgemm_matches_dense(block, shape):
     np.testing.assert_allclose(c, a @ b, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("pairs_per_call", [1, 5, 7, 1 << 20])
+def test_bsr_spgemm_chunked_calls_match_dense(monkeypatch, pairs_per_call):
+    """Pair lists longer than one call's SMEM share run as a loop of calls
+    that accumulate into the running C: a C tile whose run of pairs spans a
+    chunk boundary, a ragged last chunk, and C blocks no pair reaches (zero)
+    all agree with the dense product."""
+    from repro.kernels import bsr_spgemm as mod
+
+    rng = np.random.default_rng(7)
+    block = 8
+    a = _random_block_dense(rng, 40, 32, 0.5, block)
+    b = _random_block_dense(rng, 32, 24, 0.5, block)
+    ab, bb = to_bsr(a, block, block), to_bsr(b, block, block)
+    pa, pb, pc, crows, ccols = build_pair_lists(ab.brows, ab.bcols, bb.brows, bb.bcols)
+    monkeypatch.setattr(mod, "PAIRS_PER_CALL", pairs_per_call)
+    mod._bsr_spgemm_jit.clear_cache()
+    try:
+        # one spare C block that no pair touches
+        got = mod.bsr_spgemm(ab.blocks, bb.blocks, pa, pb, pc, len(crows) + 1)
+    finally:
+        mod._bsr_spgemm_jit.clear_cache()
+    got = np.asarray(got)
+    np.testing.assert_array_equal(got[-1], 0)
+    c = bsr_to_dense(BlockSparse(got[:-1], crows, ccols, (40, 24)))
+    np.testing.assert_allclose(c, a @ b, rtol=1e-4, atol=1e-4)
+
+
+def test_resolve_interpret_follows_the_platform(monkeypatch):
+    """CPU runs the interpreter, TPU the compiled kernel, and an explicit
+    interpreter request on a TPU is refused."""
+    from repro.kernels import resolve_interpret
+
+    assert resolve_interpret() is True
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False
+    with pytest.raises(ValueError, match="interpret mode requested on a TPU"):
+        resolve_interpret(True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="not on 'gpu'"):
+        resolve_interpret()
+
+
 def test_bsr_spgemm_pair_list_int32_cast_covers_all_operand_kinds():
     """The host-side int32 cast is one explicit helper: int64 ndarrays and
     Python lists cast host-side (no convert inside jit), already-int32

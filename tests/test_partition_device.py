@@ -132,23 +132,20 @@ def test_device_falls_back_to_flat_without_jax(device_everywhere, monkeypatch):
 
 
 def test_device_engine_failure_falls_back_to_flat(device_everywhere, monkeypatch):
-    """A device engine that *fails at runtime* (OOM, kernel error) degrades
-    to the flat engine with one warning and the identical flat result —
-    partitioning never dies because the accelerator did."""
+    """A device engine that fails at run time (OOM, kernel error) raises —
+    no silent switch to the flat engine.  Resilience lives in the session's
+    FaultPolicy, which records an ``engine_fallback`` event when it walks
+    the engine chain."""
 
-    def boom(hg, p, part_cap, seed, rd):
+    def boom(hg, p, part_cap, seed, rd, coarsen):
         raise RuntimeError("RESOURCE_EXHAUSTED: injected device OOM")
 
     monkeypatch.setattr(partition_mod, "_partition_device", boom)
     hg = build_model(_instance(1), "rowwise")
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        a = partition(hg, 4, eps=0.10, seed=0, engine="device")
-    b = partition(hg, 4, eps=0.10, seed=0, engine="flat")
-    assert np.array_equal(a.parts, b.parts)
-    assert a.connectivity == b.connectivity
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        partition(hg, 4, eps=0.10, seed=0, engine="device")  # warns once only
+        with pytest.raises(RuntimeError, match="injected device OOM"):
+            partition(hg, 4, eps=0.10, seed=0, engine="device")
 
 
 def test_unknown_engine_still_rejected():

@@ -1,0 +1,127 @@
+"""Compile the main path's kernels and executor steps for a described TPU.
+
+Nothing runs: the TPU compiler that ships with jax compiles for a v5e 2x2
+topology that is described, not attached, so the Mosaic lowering of the
+kernels (SMEM and VMEM limits, tiling) and the sharded executor steps are
+checked on every run of the suite.  The topology is described inside a
+fixture, never at import, so that only the test worker that runs this file
+loads the TPU library.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+import repro
+from repro.core.matrices import amg_instances
+from repro.kernels import bsr_spgemm as bsr_spgemm_mod
+from repro.kernels.bsr_spmm import bsr_spmm
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs go to /tmp
+    # a compile for a described chip cannot be read back from the cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "not describable here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize(
+    "block, n_pairs", [(1, 2 * bsr_spgemm_mod.PAIRS_PER_CALL + 5), (128, 600)]
+)
+def test_bsr_spgemm_compiles_for_tpu(one_chip, block, n_pairs):
+    """Mosaic accepts the pair-list kernel; at block 1 the list is longer
+    than one call's SMEM share, so the chunk loop compiles too."""
+    n_a, n_c = 4 * n_pairs // 3, n_pairs // 2
+    blocks = _sds((n_a, block, block), jnp.float32, one_chip)
+    pairs = _sds((n_pairs,), jnp.int32, one_chip)
+    compiled = bsr_spgemm_mod._bsr_spgemm_jit.lower(
+        blocks, blocks, pairs, pairs, pairs, n_c_blocks=n_c, interpret=False
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_bsr_spmm_compiles_for_tpu(one_chip):
+    nb, b = 64, 128
+    compiled = bsr_spmm.lower(
+        _sds((nb, b, b), jnp.float32, one_chip),
+        _sds((nb,), jnp.int32, one_chip),
+        _sds((nb,), jnp.int32, one_chip),
+        _sds((16 * b, 2 * b), jnp.float32, one_chip),
+        m_blocks=16,
+        interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def amg_small():
+    inst, _ = amg_instances(6)
+    return inst
+
+
+def _step_compiled(step_and_tables, mesh, axes, a_shape, b_shape):
+    step, tables = step_and_tables
+    sharding = NamedSharding(mesh, P(axes))
+    return (
+        jax.jit(step)
+        .lower(
+            _sds(a_shape, jnp.float32, sharding),
+            _sds(b_shape, jnp.float32, sharding),
+            *(jax.ShapeDtypeStruct(np.shape(t), jnp.int32) for t in tables),
+        )
+        .compile()
+    )
+
+
+def test_fine_step_compiles_for_four_tpus(topo, amg_small):
+    from repro.distributed.spgemm_exec import make_fine_step
+
+    plan = repro.plan(amg_small, p=4, model="fine").execution_plan
+    mesh = Mesh(np.array(topo.devices[:4]), ("x",))
+    compiled = _step_compiled(
+        make_fine_step(plan, mesh),
+        mesh,
+        "x",
+        (4, plan.local_ids["a_nz"].shape[1]),
+        (4, plan.local_ids["b_nz"].shape[1]),
+    )
+    assert "all-to-all" in compiled.as_text()
+
+
+def test_monoC_pallas_step_compiles_for_four_tpus(topo, amg_small):
+    from repro.distributed.spgemm_exec import make_monoC_step
+
+    plan = repro.plan(amg_small, p=4, model="monoC").execution_plan
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("x", "y"))
+    step = make_monoC_step(plan, mesh, block=1, backend="pallas", axes=("x", "y"))
+    compiled = _step_compiled(
+        step,
+        mesh,
+        ("x", "y"),
+        (4, plan.local_ids["a_nz"].shape[1], 1, 1),
+        (4, plan.local_ids["b_nz"].shape[1], 1, 1),
+    )
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-to-all" in text
