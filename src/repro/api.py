@@ -130,8 +130,15 @@ class CompiledSpGEMM:
         For a batched handle the inputs are (m, nnz) stacks; each row is
         packed independently and the stack is zero-padded to the compiled
         batch capacity (padding rows cost device flops, never correctness —
-        their products are simply dropped by ``__call__``).
+        their products are simply dropped by ``__call__``).  Runs inside
+        the host span ``repro.pack``.
         """
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation("repro.pack"):
+            return self._pack(a_values, b_values)
+
+    def _pack(self, a_values, b_values):
         block = self.runtime.block
         if self.batch_capacity is None:
             return (

@@ -233,7 +233,9 @@ def owned_nz_setup(
 ) -> RunnerSetup:
     """Runner for executors whose operands live in owned nonzero slots
     (fine, monoA, monoB, monoC, summa2d): scatter the value vectors into
-    device-major ``(p, N_max, *item_shape)`` tables, then run ``step``."""
+    device-major ``(p, N_max, *item_shape)`` tables (device scope
+    ``repro.scatter_values``), then run ``step``."""
+    import jax
     import jax.numpy as jnp
 
     p = plan.p
@@ -246,8 +248,9 @@ def owned_nz_setup(
     N_b = plan.local_ids["b_nz"].shape[1]
 
     def run(a_values, b_values, a_d, a_s, b_d, b_s, *tables):
-        a_own = jnp.zeros((p, N_a, *item_shape), dtype).at[a_d, a_s].set(a_values)
-        b_own = jnp.zeros((p, N_b, *item_shape), dtype).at[b_d, b_s].set(b_values)
+        with jax.named_scope("repro.scatter_values"):
+            a_own = jnp.zeros((p, N_a, *item_shape), dtype).at[a_d, a_s].set(a_values)
+            b_own = jnp.zeros((p, N_b, *item_shape), dtype).at[b_d, b_s].set(b_values)
         return step(a_own, b_own, *tables)
 
     return RunnerSetup(

@@ -54,6 +54,7 @@ from collections import OrderedDict
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.distributed.registry import get_spec
@@ -256,11 +257,14 @@ class CompiledSpGEMM:
         """Value-only update: returns device-major C shards (the same layout
         the underlying ``*_spgemm`` executor returns; a leading batch axis
         when compiled with ``batch=n``).  Passing a jax.Array transfers
-        ownership of its buffer (donation)."""
+        ownership of its buffer (donation).  The host span ``repro.call``
+        covers the coercion and the dispatch, which enqueues the
+        host-to-device copy; it waits for neither the copy nor the step."""
         faults.fire("execute")
-        a = self._coerce(a_values, self._a_shape, "A")
-        b = self._coerce(b_values, self._b_shape, "B")
-        return self._compiled(a, b, *self._tables)
+        with TraceAnnotation("repro.call"):
+            a = self._coerce(a_values, self._a_shape, "A")
+            b = self._coerce(b_values, self._b_shape, "B")
+            return self._compiled(a, b, *self._tables)
 
     def unpack(self, c_local) -> np.ndarray:
         """Scatter device-major C shards back to a dense (I, J) array (padded

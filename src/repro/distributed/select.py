@@ -65,41 +65,23 @@ def build_executable_plan(
 
 def _execute(handle, a_dense: np.ndarray, b_dense: np.ndarray, want: np.ndarray) -> dict:
     """Run a planned pipeline's executor on this process' devices and report
-    wall time + max error vs the dense oracle ``want`` (computed once per
+    its max error against the dense oracle ``want`` (computed once per
     instance by the caller).  Requires the process to own >= p devices (the
     multi-device CI job forces 8).
 
     Goes through the ``repro.api`` front door — mesh geometry, value
     packing, dtype promotion and backend defaults all come from the model's
     ``ModelSpec`` — with values taken straight off the instance structures
-    (no dense -> sparse round trip): ``exec_s`` is the cold cost (structure
-    work + AOT compile + first call), ``exec_warm_us`` the steady-state
-    value-only per-call latency the runtime amortizes to.
+    (no dense -> sparse round trip).
     """
-    import jax
-
     inst = handle.instance
     ar, ac = inst.a.coo()
     br, bc = inst.b.coo()
     a_vals = a_dense[ar, ac]
     b_vals = b_dense[br, bc]
-    t0 = time.time()
     exe = handle.compile(dtype=np.promote_types(a_vals.dtype, b_vals.dtype))
     got = exe(a_vals, b_vals)
-    cold_s = time.time() - t0
-    # steady-state timing on the raw runtime executable (device shards out,
-    # no host unpack), matching bench_exec's us_per_call convention
-    a_packed, b_packed = exe.pack(a_vals, b_vals)
-    reps = 3
-    t0 = time.time()
-    for _ in range(reps):
-        jax.block_until_ready(exe.runtime(a_packed, b_packed))
-    warm_us = (time.time() - t0) / reps * 1e6
-    return {
-        "exec_s": round(cold_s, 3),
-        "exec_warm_us": int(warm_us),
-        "exec_max_err": float(np.abs(got - want).max()),
-    }
+    return {"exec_max_err": float(np.abs(got - want).max())}
 
 
 def sweep_instance(

@@ -27,6 +27,12 @@ These realize the paper's algorithm classes as compiled JAX programs:
 Every sparsity-dependent executor consumes an ``ExecutionPlan``
 (``plan_ir``): ownership maps + padded routing tables + local work lists.
 
+The fine and monoC steps name their phases with ``jax.named_scope``
+(``repro.scatter_values``, ``repro.expand_a``, ``repro.expand_b``,
+``repro.local``, ``repro.reduce_c``), so a profiler trace splits the
+step's device time by phase; ``owned_c_values`` marks its host work with
+``TraceAnnotation`` spans.
+
 Structure-time vs value-time split (DESIGN.md §8): each executor's math
 lives in a ``make_*_step`` builder that returns a jit-compatible function
 over device-major *packed* operand arrays together with the plan's routing
@@ -45,6 +51,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.compat import shard_map
 
@@ -57,6 +64,16 @@ def _take0(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     rows = x[safe]
     mask = (idx >= 0).reshape((-1,) + (1,) * (x.ndim - 1))
     return jnp.where(mask, rows, 0)
+
+
+def _own_tables(a_blk: jnp.ndarray, b_blk: jnp.ndarray):
+    """This device's owned value tables out of its (1, N_max, ...) blocks.
+
+    The runner's value scatter (``registry.owned_nz_setup``) fuses into
+    this squeeze, and a fused operation takes its scope from its last
+    step, so the squeeze carries the scatter's scope."""
+    with jax.named_scope("repro.scatter_values"):
+        return a_blk[0], b_blk[0]
 
 
 # ---------------------------------------------------------------------------
@@ -286,11 +303,15 @@ def make_monoC_step(
         return jnp.concatenate([own, recv.reshape(p * T, block, block), zero], 0)
 
     def step(a_blk, b_blk, sa_, sb_, pa_, pb_, pc_):
-        a_tab = expand(a_blk[0], sa_[0], T_a)
-        b_tab = expand(b_blk[0], sb_[0], T_b)
-        c = bsr_spgemm_local(
-            a_tab, b_tab, pa_[0], pb_[0], pc_[0], n_c_blocks=n_c_slots, backend=backend
-        )
+        a_own, b_own = _own_tables(a_blk, b_blk)
+        with jax.named_scope("repro.expand_a"):
+            a_tab = expand(a_own, sa_[0], T_a)
+        with jax.named_scope("repro.expand_b"):
+            b_tab = expand(b_own, sb_[0], T_b)
+        with jax.named_scope("repro.local"):
+            c = bsr_spgemm_local(
+                a_tab, b_tab, pa_[0], pb_[0], pc_[0], n_c_blocks=n_c_slots, backend=backend
+            )
         return c[None]
 
     spec = P(axes)
@@ -355,9 +376,18 @@ def owned_c_values(c_local: jnp.ndarray, plan) -> np.ndarray:
     Works for every plan whose C lives in owned slots (fine, monoA, monoB,
     monoC, summa2d): ``plan.local_ids["c_nz"]`` names the C nonzero each
     slot holds.  The result is ``(nnz(C),)`` for scalar plans and
-    ``(nnz(C), b, b)`` for blocked ones; nothing is densified.
+    ``(nnz(C), b, b)`` for blocked ones; nothing is densified.  The
+    device-to-host copy and the reorder are the host spans
+    ``repro.unpack.fetch`` and ``repro.unpack.reorder``.
     """
-    c_np = np.asarray(c_local)
+    with TraceAnnotation("repro.unpack.fetch"):
+        c_np = np.asarray(c_local)
+    with TraceAnnotation("repro.unpack.reorder"):
+        # a call of its own, so that freeing its temporaries counts here
+        return _canonical_order(c_np, plan)
+
+
+def _canonical_order(c_np: np.ndarray, plan) -> np.ndarray:
     local_c = plan.local_ids["c_nz"]
     dev, slot = np.nonzero(local_c >= 0)
     out = np.empty((len(plan.ownership["c_nz"]), *c_np.shape[2:]), c_np.dtype)
@@ -424,29 +454,37 @@ def make_fine_step(plan: FinePlan, mesh: Mesh, axis: str = "x"):
         return jnp.concatenate([own, recv.reshape(p * T), zero], 0)
 
     def step(a_blk, b_blk, sa_, sb_, sr_, pa_, pb_, pc_, recv_slot_all, prod_own_):
-        a_tab = expand(a_blk[0], sa_[0], T_a)
-        b_tab = expand(b_blk[0], sb_[0], T_b)
-        # local compute: exactly this device's multiplication vertices
-        prods = a_tab[pa_[0]] * b_tab[pb_[0]]
-        partial = jnp.zeros((R_max + 1,), a_tab.dtype).at[pc_[0]].add(prods)
-        # reduce phase: ship foreign partials to their C owners
-        buf = _take0(partial, sr_[0].reshape(-1)).reshape(p, T_r)
-        recv = jax.lax.all_to_all(
-            buf[None], axis, split_axis=1, concat_axis=1, tiled=False
-        )[0]
-        me = jax.lax.axis_index(axis)
-        slots = recv_slot_all[:, me].reshape(-1)  # owned-C slot per arrival
-        ok = slots >= 0
-        c = jnp.zeros((C_max + 1,), a_tab.dtype)
-        c = c.at[jnp.where(ok, slots, C_max)].add(
-            jnp.where(ok, recv.reshape(-1), 0)
-        )
-        # partials this device both produced and owns fold locally
-        own_map = prod_own_[0]
-        okp = own_map >= 0
-        c = c.at[jnp.where(okp, own_map, C_max)].add(
-            jnp.where(okp, partial[:R_max], 0)
-        )
+        a_own, b_own = _own_tables(a_blk, b_blk)
+        with jax.named_scope("repro.expand_a"):
+            a_tab = expand(a_own, sa_[0], T_a)
+        with jax.named_scope("repro.expand_b"):
+            b_tab = expand(b_own, sb_[0], T_b)
+        with jax.named_scope("repro.local"):
+            # one zero table, sliced for the partial and the owned-C tables:
+            # the compiler merges equal constants (R_max == C_max at p=1),
+            # and a merged one keeps no scope
+            zero = jnp.zeros((max(R_max, C_max) + 1,), a_tab.dtype)
+            # exactly this device's multiplication vertices
+            prods = a_tab[pa_[0]] * b_tab[pb_[0]]
+            partial = zero[: R_max + 1].at[pc_[0]].add(prods)
+            # partials this device both produced and owns fold locally
+            own_map = prod_own_[0]
+            okp = own_map >= 0
+            c = zero[: C_max + 1].at[jnp.where(okp, own_map, C_max)].add(
+                jnp.where(okp, partial[:R_max], 0)
+            )
+        with jax.named_scope("repro.reduce_c"):
+            # ship foreign partials to their C owners and fold them in
+            buf = _take0(partial, sr_[0].reshape(-1)).reshape(p, T_r)
+            recv = jax.lax.all_to_all(
+                buf[None], axis, split_axis=1, concat_axis=1, tiled=False
+            )[0]
+            me = jax.lax.axis_index(axis)
+            slots = recv_slot_all[:, me].reshape(-1)  # owned-C slot per arrival
+            ok = slots >= 0
+            c = c.at[jnp.where(ok, slots, C_max)].add(
+                jnp.where(ok, recv.reshape(-1), 0)
+            )
         return c[None]
 
     shard = shard_map(
