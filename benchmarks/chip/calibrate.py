@@ -39,7 +39,9 @@ def readings(cell, seed: int, seconds: float) -> dict:
     refs = harness.references(cell, [i for i, _ in win.sample])
     program = max(refs[i].error(got) for i, got in win.sample)
     control = max(
-        ref.error(reference.control(cell.a, cell.b, ref.c_keys, *cell.pool[i]))
+        ref.error(reference.control(cell.a, cell.b, ref.c_keys, *cell.pool[i],
+                                    blocks=harness.blocks(cell.config),
+                                    positions=ref.positions))
         for i, ref in refs.items()
     )
     return {"seed": seed, "products": len(win.latencies), "sampled": len(win.sample),

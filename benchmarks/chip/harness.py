@@ -5,7 +5,10 @@ Everything a cell is made of is found by name from ``BENCHMARK.json``:
 - the cell (``workloads``) names a configuration and a traffic mix;
 - the configuration is the JSON file its ``configs`` entry names, and it
   names its generator, ``generators/<generator>.py``, which builds the
-  operand structures and the value pool;
+  operand structures and the value pool; a configuration of block-sparse
+  operands declares ``"blocks": {"a": [r, k], "b": [k, c]}``, its
+  structures are then over block rows and columns and its values are
+  (nnz, r, k) and (nnz, k, c) arrays (``blocks``);
 - the traffic mix is ``traffic/<traffic>.json``, a file of parameters
   whose ``loop`` names the driver that reads them, ``loops/<loop>.py``:
   its ``warm(cell)`` runs in set-up and its ``drive(cell, seconds, seed)``
@@ -146,6 +149,17 @@ def seed_int(seed: int) -> int:
     return int(seed) % (1 << 64)
 
 
+def blocks(cfg: dict):
+    """((r, k), (k, c)), the blocks of A and B a configuration declares, or
+    None where it declares none: scalar operands, today's path."""
+    if "blocks" not in cfg:
+        return None
+    a, b = (tuple(int(n) for n in cfg["blocks"][key]) for key in ("a", "b"))
+    if len(a) != 2 or len(b) != 2 or min(a + b) < 1 or a[1] != b[0]:
+        raise ValueError(f"blocks {cfg['blocks']} are not r x k and k x c")
+    return a, b
+
+
 # ---------------------------------------------------------------------------
 # set-up
 # ---------------------------------------------------------------------------
@@ -206,7 +220,7 @@ class Cell:
     loop: object  # the traffic mix's loop module
     a: object  # scipy CSR structures of the operands
     b: object
-    pool: list  # value sets (A values, B values), float32, canonical order
+    pool: list  # value sets (A values, B values), float32, canonical (block) order
     exe: object  # repro.api.CompiledSpGEMM
     plan: object  # its execution plan
     events: list  # the session's events in set-up
@@ -226,13 +240,19 @@ class Cell:
             return owned_c_values(c, self.plan).reshape(-1)
 
     def sizes(self) -> dict:
+        """Counts of the planned product.  Nonzeros and multiplications are
+        of blocks: a value set is nnz x r x k items, a multiplication is
+        2 r k c operations."""
         inst = self.exe.planned.instance
+        a_block, b_block = blocks(self.config) or ((1, 1), (1, 1))
         return {
             "nnz_a": int(inst.a.nnz),
             "nnz_b": int(inst.b.nnz),
             "nnz_c": int(inst.c.nnz),
             "n_mult": int(inst.n_mult),
             "itemsize": np.dtype(self.config["dtype"]).itemsize,
+            "a_block": list(a_block),
+            "b_block": list(b_block),
         }
 
     def release(self) -> None:
@@ -253,11 +273,13 @@ def plan(bench: Bench, cfg: dict, a, b, log=print):
     import repro
     from repro.sparse.structure import SparseStructure
 
+    declared = {} if blocks(cfg) is None else {"blocks": blocks(cfg)}
+
     def entry():
         session = repro.session(
             p=cfg["p"], model=cfg["model"], eps=cfg["eps"], seed=cfg["plan_seed"],
             engine=cfg["engine"], store_dir=str(bench.store_dir()),
-            dtype=np.dtype(cfg["dtype"]),
+            dtype=np.dtype(cfg["dtype"]), **declared,
         )
         got = session.entry_for(SparseStructure.wrap(a), SparseStructure.wrap(b))
         return got, [e.kind for e in session.events]
@@ -292,6 +314,12 @@ def setup(bench: Bench, name: str, seed: int, log=print) -> Cell:
         f"({time.perf_counter() - t:.2f} s)")
     entry, events = plan(bench, cfg, a, b, log)
     pool = gen.value_pool(cfg, a, b, seed_int(seed), traffic["pool"])
+    a_block, b_block = blocks(cfg) or ((), ())
+    want = (a.nnz, *a_block), (b.nnz, *b_block)
+    for a_vals, b_vals in pool:
+        if (np.shape(a_vals), np.shape(b_vals)) != want:
+            raise ValueError(f"the value pool has shapes {np.shape(a_vals)} and "
+                             f"{np.shape(b_vals)}, the configuration {want[0]} and {want[1]}")
     cell = Cell(name, spec, cfg, traffic, gen, loop, a, b, pool, entry.exe,
                 entry.planned.execution_plan, events)
     t = time.perf_counter()
@@ -348,7 +376,11 @@ def references(cell: Cell, indices) -> dict:
     from . import reference
 
     c_keys = reference.linear_keys(reference.symbolic(cell.a, cell.b))
-    return {i: reference.Reference(cell.a, cell.b, c_keys, *cell.pool[i])
+    declared = blocks(cell.config)
+    positions = None if declared is None else reference.BlockPositions(
+        c_keys, cell.a, cell.b, declared)
+    return {i: reference.Reference(cell.a, cell.b, c_keys, *cell.pool[i],
+                                   blocks=declared, positions=positions)
             for i in sorted(set(indices))}
 
 

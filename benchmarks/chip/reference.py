@@ -15,6 +15,14 @@ obeys, so a lost or altered term shows on a small entry as plainly as on a
 large one.  The control puts this reference, computed in bfloat16, in the
 program's place: operands and result rounded to bfloat16, the precision
 below the float32 that the configurations state.
+
+A configuration may declare block-sparse operands, r x k blocks of A and
+k x c blocks of B (``blocks``).  The structures are then block structures,
+the values come as (nnz, r, k) and (nnz, k, c) arrays in block CSR order,
+and C's values are read block by block in C's canonical block order,
+row-major within a block.  Each operand is expanded to its scalar CSR and
+the product and the scale are formed and compared entry by entry as above.
+Without ``blocks`` every step is the scalar one.
 """
 from __future__ import annotations
 
@@ -59,15 +67,66 @@ def at_structure(m: sp.csr_matrix, c_keys: np.ndarray) -> np.ndarray:
     return out
 
 
-class Reference:
-    """scipy's A @ B for one value set, with the per-entry rounding scale."""
+def bsr_with(structure: sp.csr_matrix, values: np.ndarray, block) -> sp.csr_matrix:
+    """The scalar CSR of a block operand: ``structure`` over block rows and
+    columns, ``values`` of shape (nnz, *block) in its order."""
+    r, c = block
+    return sp.bsr_matrix(
+        (values, structure.indices, structure.indptr),
+        shape=(structure.shape[0] * r, structure.shape[1] * c),
+    ).tocsr()
 
-    def __init__(self, a, b, c_keys, a_vals, b_vals):
+
+class BlockPositions:
+    """C's scalar positions, each block's r x c in turn in C's canonical
+    block order: ``at(m)`` reads ``m`` there.  Built once for any number of
+    value sets.  The positions are held as a sorted scalar CSR whose values
+    are the block-major index of each, so the values read in sorted order
+    are put back in block-major order."""
+
+    def __init__(self, c_keys: np.ndarray, a, b, blocks):
+        block = (blocks[0][0], blocks[1][1])
+        n_rows, n_cols = a.shape[0], b.shape[1]
+        rows, cols = np.divmod(c_keys, n_cols)
+        indptr = np.zeros(n_rows + 1, np.int64)
+        np.cumsum(np.bincount(rows, minlength=n_rows), out=indptr[1:])
+        c = sp.csr_matrix((np.ones(len(c_keys), bool), cols, indptr), shape=(n_rows, n_cols))
+        slot = np.arange(len(c_keys) * block[0] * block[1], dtype=np.int64)
+        self.scalar = bsr_with(c, slot.reshape(len(c_keys), *block), block)
+        self.scalar.sort_indices()
+
+    def at(self, m: sp.csr_matrix) -> np.ndarray:
+        m.sort_indices()
+        c = self.scalar
+        if np.array_equal(m.indptr, c.indptr) and np.array_equal(m.indices, c.indices):
+            values = m.data
+        else:  # scipy dropped a sum that came out exactly zero
+            values = at_structure(m, linear_keys(c))
+        out = np.empty(c.nnz)
+        out[c.data] = values
+        return out
+
+
+class Reference:
+    """scipy's A @ B for one value set, with the per-entry rounding scale.
+
+    ``blocks`` is ((r, k), (k, c)) for block operands, None for scalar ones;
+    ``positions``, C's ``BlockPositions``, may be shared by many value sets."""
+
+    def __init__(self, a, b, c_keys, a_vals, b_vals, blocks=None, positions=None):
         self.c_keys = c_keys
-        self.want = at_structure(csr_with(a, a_vals) @ csr_with(b, b_vals), c_keys)
-        self.scale = at_structure(
-            csr_with(a, np.abs(a_vals)) @ csr_with(b, np.abs(b_vals)), c_keys
-        )
+        self.positions = positions
+        if blocks is None:
+            self.want = at_structure(csr_with(a, a_vals) @ csr_with(b, b_vals), c_keys)
+            self.scale = at_structure(
+                csr_with(a, np.abs(a_vals)) @ csr_with(b, np.abs(b_vals)), c_keys
+            )
+        else:
+            pos = self.positions = positions or BlockPositions(c_keys, a, b, blocks)
+            a64 = bsr_with(a, np.asarray(a_vals, np.float64), blocks[0])
+            b64 = bsr_with(b, np.asarray(b_vals, np.float64), blocks[1])
+            self.want = pos.at(a64 @ b64)
+            self.scale = pos.at(abs(a64) @ abs(b64))
         self.scale[self.scale == 0] = np.finfo(np.float64).tiny
 
     def error(self, got) -> float:
@@ -83,6 +142,9 @@ def bf16(x) -> np.ndarray:
     return np.asarray(x, np.float32).astype(ml_dtypes.bfloat16).astype(np.float64)
 
 
-def control(a, b, c_keys, a_vals, b_vals) -> np.ndarray:
+def control(a, b, c_keys, a_vals, b_vals, blocks=None, positions=None) -> np.ndarray:
     """The reference in bfloat16: operands and result rounded to it."""
-    return bf16(at_structure(csr_with(a, bf16(a_vals)) @ csr_with(b, bf16(b_vals)), c_keys))
+    if blocks is None:
+        return bf16(at_structure(csr_with(a, bf16(a_vals)) @ csr_with(b, bf16(b_vals)), c_keys))
+    pos = positions or BlockPositions(c_keys, a, b, blocks)
+    return bf16(pos.at(bsr_with(a, bf16(a_vals), blocks[0]) @ bsr_with(b, bf16(b_vals), blocks[1])))
