@@ -42,9 +42,11 @@ from repro.core.comm import (
     memory_independent_bound,
 )
 from repro.core.hypergraph import Hypergraph
-from repro.core.spgemm_models import SpGEMMInstance
+from repro.core.spgemm_models import SpGEMMInstance, block_net_costs
 from repro.distributed.plan_ir import (
+    SCALAR_BLOCKS,
     ExecutionPlan,
+    block_areas,
     build_volume_plan,
     measured_route_words,
     route_messages,
@@ -52,6 +54,7 @@ from repro.distributed.plan_ir import (
 from repro.distributed.registry import (
     MODEL_SPECS,
     ModelSpec,
+    checked_blocks,
     executable_models,
     get_spec,
 )
@@ -103,7 +106,8 @@ class CompiledSpGEMM:
         self.spec = spec
         if out_shape is None:
             I, _, J = planned.instance.shape
-            out_shape = (I, J)
+            (r, _), (_, c) = planned.execution_plan.blocks
+            out_shape = (I * r, J * c)
         self._out = tuple(out_shape)
 
     @property
@@ -382,8 +386,11 @@ def _plan_one(
     warm_start: np.ndarray | None = None,
     warm_drift_limit: float = 0.5,
     coarsen: str = "auto",
+    blocks=SCALAR_BLOCKS,
 ) -> PlannedSpGEMM:
     spec = get_spec(model)
+    blocks = checked_blocks(model, blocks)
+    blocked = blocks != SCALAR_BLOCKS
     if spec.build is None:
         # partition-free baseline (summa2d): no hypergraph to build or
         # partition — lower the instance straight to its execution plan
@@ -397,6 +404,8 @@ def _plan_one(
             seed=seed,
         )
     hg = spec.build(inst, include_nz=include_nz)
+    if blocked:
+        hg = block_net_costs(hg, block_areas(blocks))
     res = _partition(
         hg,
         p,
@@ -409,7 +418,8 @@ def _plan_one(
     )
     plan_obj = None
     if spec.lower is not None and (not include_nz or spec.lower_include_nz):
-        plan_obj = spec.lower(inst, res.parts, p)
+        lower_blocks = {"blocks": blocks} if blocked else {}
+        plan_obj = spec.lower(inst, res.parts, p, **lower_blocks)
     return PlannedSpGEMM(
         instance=inst,
         model=model,
@@ -432,6 +442,7 @@ def plan(
     include_nz: bool = False,
     engine: str = "flat",
     coarsen: str = "auto",
+    blocks=None,
 ) -> PlannedSpGEMM:
     """Plan a distributed SpGEMM: model the instance, partition, lower.
 
@@ -455,6 +466,13 @@ def plan(
     ``engine="device"`` descend (``"auto"``/``"device"`` keep the V-cycle
     device-resident, ``"host"`` forces the host-scipy descend) and is
     ignored by the host engines.
+
+    ``blocks=((r, k), (k, c))`` plans block-sparse operands: ``A`` / ``B``
+    are then the block structures, every nonzero an r x k block of A or a
+    k x c block of B, values are (nnz, r, k) / (nnz, k, c) arrays in block
+    CSR order and C's are (nnz(C), r, c).  The fine executor family (fine,
+    monoA, monoB) takes them, its net costs and route words weighted by the
+    block areas; any other model, ``"auto"`` included, refuses them.
     """
     if isinstance(A, SpGEMMInstance):
         if B is not None:
@@ -471,8 +489,10 @@ def plan(
                 f"{tuple(MODEL_SPECS)} or 'auto'"
             )
         return _plan_one(
-            inst, model, p, eps, seed, include_nz, engine, coarsen=coarsen
+            inst, model, p, eps, seed, include_nz, engine, coarsen=coarsen,
+            blocks=blocks,
         )
+    checked_blocks(model, blocks)
     candidates = [
         _plan_one(inst, m, p, eps, seed, include_nz, engine, coarsen=coarsen)
         for m in executable_models()
@@ -511,7 +531,8 @@ def session(
     executor when the structure is unchanged, warm-start-replans on drift,
     persists plans under ``store_dir`` (a restarted session rebuilds its
     pool from there), and retries/downgrades through ``policy`` (a
-    ``repro.FaultPolicy``) on stage failures.  See
+    ``repro.FaultPolicy``) on stage failures.  ``blocks=((r, k), (k, c))``
+    (a keyword argument) plans block operands as ``plan`` does.  See
     ``repro.distributed.session`` for the full contract.
     """
     from repro.distributed.session import SpGEMMSession

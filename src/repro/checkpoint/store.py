@@ -318,6 +318,7 @@ def save_plan(
             for name, route in plan.routes.items()
         },
         "stats": {k: int(v) for k, v in plan.stats.items()},
+        "blocks": [list(shape) for shape in plan.blocks],
         "arrays_sha256": _sha256(arr_path),
         "meta": meta or {},
     }
@@ -361,7 +362,7 @@ def _read_plan_entry(entry_dir: str, key: str) -> RestoredPlan:
             f"plan {key!r}: unknown plan class {manifest.get('plan_class')!r}"
         )
 
-    from repro.distributed.plan_ir import Route
+    from repro.distributed.plan_ir import SCALAR_BLOCKS, Route
 
     try:
         with np.load(arr_path) as z:
@@ -407,6 +408,8 @@ def _read_plan_entry(entry_dir: str, key: str) -> RestoredPlan:
             routes=routes,
             compute=compute,
             stats=dict(manifest["stats"]),
+            # entries written before block operands are all scalar
+            blocks=tuple(tuple(b) for b in manifest.get("blocks", SCALAR_BLOCKS)),
         )
     except KeyError as e:
         raise PlanStoreError(f"plan {key!r}: manifest/arrays mismatch: {e}") from e

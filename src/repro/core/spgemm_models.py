@@ -14,6 +14,7 @@ Net kinds: 1/2/3 = A/B/C nets.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -138,6 +139,14 @@ def build_model(inst: SpGEMMInstance, model: str, include_nz: bool = False) -> H
     if model not in MODELS:
         raise ValueError(f"unknown model {model!r}; choose from {MODELS}")
     return globals()[f"_build_{model}"](inst, include_nz)
+
+
+def block_net_costs(hg: Hypergraph, areas: tuple[int, int, int]) -> Hypergraph:
+    """``hg`` over block operands: every item an A-, B- or C-net ships is
+    one r x k, k x c or r x c block, so each net's cost is multiplied by
+    that area (``areas`` = (r*k, k*c, r*c), indexed by the net kind)."""
+    scale = np.array([1, *areas], dtype=np.int64)
+    return dataclasses.replace(hg, net_cost=hg.net_cost * scale[hg.net_kind])
 
 
 # ---------------------------------------------------------------------------

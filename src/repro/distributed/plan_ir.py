@@ -87,9 +87,37 @@ class Route:
         return (self.items_padded - self.items_ideal) / max(self.items_padded, 1)
 
 
+#: the block shapes ((r, k), (k, c)) of scalar operands
+SCALAR_BLOCKS = ((1, 1), (1, 1))
+
+
+def as_blocks(blocks) -> tuple[tuple[int, int], tuple[int, int]]:
+    """``((r, k), (k, c))``: A's r x k and B's k x c blocks, one per
+    nonzero of the block structures (None: scalar operands)."""
+    if blocks is None:
+        return SCALAR_BLOCKS
+    try:
+        (r, k), (k2, c) = ((int(n) for n in shape) for shape in blocks)
+    except (TypeError, ValueError):
+        raise ValueError(f"blocks {blocks!r} are not ((r, k), (k, c))") from None
+    if k != k2 or min(r, k, c) < 1:
+        raise ValueError(f"blocks {blocks!r} are not r x k and k x c")
+    return (r, k), (k, c)
+
+
+def block_areas(blocks) -> tuple[int, int, int]:
+    """Items of one A, B and C block: r*k, k*c and r*c."""
+    (r, k), (_, c) = blocks
+    return r * k, k * c, r * c
+
+
 @dataclasses.dataclass
 class ExecutionPlan:
-    """Model-agnostic inspector output: ownership + routing + local work."""
+    """Model-agnostic inspector output: ownership + routing + local work.
+
+    ``blocks`` is ((r, k), (k, c)) where the planned structures are block
+    structures and every nonzero of A / B an r x k / k x c block (the fine
+    executor family); the routes' ``word_size`` are then the block areas."""
 
     model: str
     p: int
@@ -98,6 +126,7 @@ class ExecutionPlan:
     routes: dict[str, Route] = dataclasses.field(default_factory=dict)
     compute: dict[str, np.ndarray] = dataclasses.field(default_factory=dict)
     stats: dict = dataclasses.field(default_factory=dict)
+    blocks: tuple = SCALAR_BLOCKS
 
     @property
     def comm_words_ideal(self) -> int:
@@ -635,7 +664,7 @@ def build_fine_plan(
     a_part: np.ndarray | None = None,
     b_part: np.ndarray | None = None,
     c_part: np.ndarray | None = None,
-    word_size: int = 1,
+    blocks=SCALAR_BLOCKS,
 ) -> FinePlan:
     """Lower a fine-grained (flop-level) partition to an executable plan.
 
@@ -645,7 +674,14 @@ def build_fine_plan(
     ownership maps.  Ownership not provided either way is derived from the
     pins (``derive_owner_from_pins``), which makes ``comm_words_ideal``
     equal the fine hypergraph's connectivity cost exactly.
+
+    ``blocks`` ((r, k), (k, c)) plans block operands: the instance is over
+    block structures, a multiplication is one r x k by k x c block product,
+    and the three routes ship r*k, k*c and r*c words an item, the net costs
+    of the blocked fine hypergraph (``spgemm_models.block_net_costs``).
     """
+    blocks = as_blocks(blocks)
+    words_a, words_b, words_c = block_areas(blocks)
     M = inst.n_mult
     nA, nB, nC = inst.a.nnz, inst.b.nnz, inst.c.nnz
     mult_part = np.asarray(mult_part, dtype=np.int64)
@@ -680,10 +716,10 @@ def build_fine_plan(
     # expand routes: exactly the cut A-/B-net traffic of the fine partition
     local_a, local_of_a = padded_id_lists(a_part, p)
     src, dst, items = _expand_transfers(a_pos, mult_dev, a_part, p)
-    route_a = build_route(src, dst, items, local_of_a, p, "A", word_size)
+    route_a = build_route(src, dst, items, local_of_a, p, "A", words_a)
     local_b, local_of_b = padded_id_lists(b_part, p)
     src, dst, items = _expand_transfers(b_pos, mult_dev, b_part, p)
-    route_b = build_route(src, dst, items, local_of_b, p, "B", word_size)
+    route_b = build_route(src, dst, items, local_of_b, p, "B", words_b)
     local_c, local_of_c = padded_id_lists(c_part, p)
 
     # produced-C table: the distinct C nonzeros each device contributes to,
@@ -735,7 +771,7 @@ def build_fine_plan(
         local_of_c,
         p,
         "C",
-        word_size,
+        words_c,
         send_slot=prod_slot[r_src[keep], r_item[keep]],
     )
     recv_slot = np.where(
@@ -762,6 +798,7 @@ def build_fine_plan(
             "prod_to_owned": prod_owned,
         },
         stats={"n_mult": int(M), "pairs_padded": int(p * P_max)},
+        blocks=blocks,
     )
 
 

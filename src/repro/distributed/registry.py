@@ -40,7 +40,10 @@ import numpy as np
 
 from repro.core.spgemm_models import MODELS, SpGEMMInstance, build_model
 from repro.distributed.plan_ir import (
+    SCALAR_BLOCKS,
     ExecutionPlan,
+    as_blocks,
+    block_areas,
     build_fine_plan,
     build_monoC_plan,
     build_outer_plan,
@@ -132,8 +135,10 @@ def _lower_monoC(inst: SpGEMMInstance, parts: np.ndarray, p: int) -> ExecutionPl
     return build_monoC_plan(inst, parts, p, a_part=a_part, b_part=b_part)
 
 
-def _lower_fine(inst: SpGEMMInstance, parts: np.ndarray, p: int) -> ExecutionPlan:
-    return build_fine_plan(inst, parts, p)
+def _lower_fine(
+    inst: SpGEMMInstance, parts: np.ndarray, p: int, blocks=SCALAR_BLOCKS
+) -> ExecutionPlan:
+    return build_fine_plan(inst, parts, p, blocks=blocks)
 
 
 def _transposed_instance(inst: SpGEMMInstance) -> SpGEMMInstance:
@@ -151,23 +156,27 @@ def _lower_columnwise(inst: SpGEMMInstance, parts: np.ndarray, p: int) -> Execut
     return plan
 
 
-def _lower_monoA(inst: SpGEMMInstance, parts: np.ndarray, p: int) -> ExecutionPlan:
+def _lower_monoA(
+    inst: SpGEMMInstance, parts: np.ndarray, p: int, blocks=SCALAR_BLOCKS
+) -> ExecutionPlan:
     # monoA vertices are A nonzeros; colocating every multiplication with
     # its A nonzero makes expand_a empty, expand_b ship each b_kj to the
     # parts of A-column k (= the pins of B-net n^B_k, so items weighted by
     # the net's nnz(B row k) cost sum to exactly the B-net connectivity)
     # and reduce_c ship lambda - 1 partials per C net — measured == predicted
     parts = np.asarray(parts, dtype=np.int64)
-    plan = build_fine_plan(inst, parts[inst.mult_a_pos], p, a_part=parts)
+    plan = build_fine_plan(inst, parts[inst.mult_a_pos], p, a_part=parts, blocks=blocks)
     plan.model = "monoA"
     return plan
 
 
-def _lower_monoB(inst: SpGEMMInstance, parts: np.ndarray, p: int) -> ExecutionPlan:
+def _lower_monoB(
+    inst: SpGEMMInstance, parts: np.ndarray, p: int, blocks=SCALAR_BLOCKS
+) -> ExecutionPlan:
     # symmetric to monoA with B stationary (vertices are B nonzeros in CSR
     # order, matching the monoB builder's pin convention)
     parts = np.asarray(parts, dtype=np.int64)
-    plan = build_fine_plan(inst, parts[inst.mult_b_pos], p, b_part=parts)
+    plan = build_fine_plan(inst, parts[inst.mult_b_pos], p, b_part=parts, blocks=blocks)
     plan.model = "monoB"
     return plan
 
@@ -262,10 +271,65 @@ def owned_nz_setup(
     )
 
 
+def block_nz_setup(
+    plan, a_structure, b_structure, step, step_tables, *, dtype, out_shape
+) -> RunnerSetup:
+    """Runner for the fine family over block operands (``plan.blocks`` =
+    ((r, k), (k, c))).  Values arrive flat in block CSR order, (nnz*r*k,)
+    and (nnz*k*c,), block after block: a host array of (nnz, 3, 3) blocks
+    would be padded to the TPU's (8, 128) tiles in its last two dimensions
+    on the way in.  On the device (scope ``repro.block_layout``) they turn
+    items-major, (r*k, nnz); the scatter into the owned tables
+    (``repro.scatter_values``) moves whole columns; and the step's
+    items-major C, (p, r*c, slots), goes back flat and block-major,
+    (p*slots*r*c,), for ``spgemm_exec.owned_c_values``."""
+    import jax
+    import jax.numpy as jnp
+
+    p = plan.p
+    nA, nB = a_structure.nnz, b_structure.nnz
+    if nA != len(plan.a_part) or nB != len(plan.b_part):
+        raise ValueError("plan was built for a different nonzero structure")
+    items_a, items_b, _ = block_areas(plan.blocks)
+    adev, aslot = owner_slot(plan.local_ids["a_nz"], nA)
+    bdev, bslot = owner_slot(plan.local_ids["b_nz"], nB)
+    N_a = plan.local_ids["a_nz"].shape[1]
+    N_b = plan.local_ids["b_nz"].shape[1]
+
+    def owned(values, n, items, n_max, col):
+        with jax.named_scope("repro.block_layout"):
+            by_item = values.reshape(n, items).T
+        with jax.named_scope("repro.scatter_values"):
+            tab = jnp.zeros((items, p * n_max), dtype).at[:, col].set(by_item)
+            return tab.reshape(items, p, n_max).transpose(1, 0, 2)
+
+    def run(a_values, b_values, a_col, b_col, *tables):
+        a_own = owned(a_values, nA, items_a, N_a, a_col)
+        b_own = owned(b_values, nB, items_b, N_b, b_col)
+        c = step(a_own, b_own, *tables)
+        with jax.named_scope("repro.block_layout"):
+            return c.transpose(0, 2, 1).reshape(-1)
+
+    return RunnerSetup(
+        run,
+        (nA * items_a,),
+        (nB * items_b,),
+        out_shape,
+        # each nonzero's column in the (items, p * N_max) scatter target
+        (adev * N_a + aslot, bdev * N_b + bslot, *step_tables),
+    )
+
+
 def _fine_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend, axis, axes):
     from repro.distributed import spgemm_exec as _exec
 
     step, tables = _exec.make_fine_step(plan, mesh, axis=axis)
+    if plan.blocks != SCALAR_BLOCKS:
+        (r, _), (_, c) = plan.blocks
+        out_shape = (a_structure.shape[0] * r, b_structure.shape[1] * c)
+        return block_nz_setup(
+            plan, a_structure, b_structure, step, tables, dtype=dtype, out_shape=out_shape
+        )
     out_shape = (a_structure.shape[0], b_structure.shape[1])
     return owned_nz_setup(
         plan, a_structure, b_structure, step, tables, dtype=dtype, out_shape=out_shape
@@ -353,6 +417,13 @@ def _values_flat(vals: np.ndarray, block: int) -> np.ndarray:
     return vals
 
 
+def _values_items(vals: np.ndarray, block: int) -> np.ndarray:
+    # the fine family: scalars as they are, (nnz, r, k) blocks flattened
+    # block after block (a view, no copy)
+    vals = np.asarray(vals)
+    return vals.reshape(-1) if vals.ndim == 3 else vals
+
+
 def _values_blocked(vals: np.ndarray, block: int) -> np.ndarray:
     return np.asarray(vals).reshape(-1, block, block)
 
@@ -401,6 +472,7 @@ class ModelSpec:
     pack_values: Callable = _values_flat  # (vals, block) -> executor layout
     item_words: Callable = lambda inst: None  # (inst) -> {route: words-per-item}
     needs_c_structure: bool = False  # unpack requires inst.c
+    block_operands: bool = False  # plans and runs r x k . k x c block operands
     lower_include_nz: bool = False  # lowerer accepts include_nz partitions
     compile_defaults: dict = dataclasses.field(default_factory=dict)
     measured: str | None = None  # "exact" | "useful" | None
@@ -459,7 +531,9 @@ MODEL_SPECS: dict[str, ModelSpec] = {
         lower=_lower_fine,
         make_runner=_fine_runner,
         unpack=_unpack_fine,
+        pack_values=_values_items,
         needs_c_structure=True,
+        block_operands=True,
         # build_fine_plan adopts include_nz vertex placements as ownership
         lower_include_nz=True,
         measured="exact",
@@ -504,7 +578,9 @@ MODEL_SPECS: dict[str, ModelSpec] = {
         lower=_lower_monoA,
         make_runner=_fine_runner,
         unpack=_unpack_fine,
+        pack_values=_values_items,
         needs_c_structure=True,
+        block_operands=True,
         measured="exact",
         notes="A nonzero stationary; mults colocated with A, fine executor",
     ),
@@ -515,7 +591,9 @@ MODEL_SPECS: dict[str, ModelSpec] = {
         lower=_lower_monoB,
         make_runner=_fine_runner,
         unpack=_unpack_fine,
+        pack_values=_values_items,
         needs_c_structure=True,
+        block_operands=True,
         measured="exact",
         notes="B nonzero stationary; mults colocated with B, fine executor",
     ),
@@ -574,6 +652,20 @@ def get_spec(model: str) -> ModelSpec:
         raise ValueError(
             f"unknown model {model!r}; choose from {tuple(MODEL_SPECS)}"
         ) from None
+
+
+def checked_blocks(model: str, blocks) -> tuple:
+    """``blocks`` as ((r, k), (k, c)) (``plan_ir.as_blocks``).  A model
+    whose executor cannot run block operands (all but the fine family, and
+    "auto", which would choose among them) refuses any but 1 x 1, by name."""
+    blocks = as_blocks(blocks)
+    if blocks != SCALAR_BLOCKS and (model == "auto" or not get_spec(model).block_operands):
+        takes = tuple(n for n, s in MODEL_SPECS.items() if s.block_operands)
+        raise ValueError(
+            f"model {model!r} does not take block operands {blocks}; "
+            f"the models that do: {takes}"
+        )
+    return blocks
 
 
 def executable_models() -> tuple[str, ...]:

@@ -117,7 +117,7 @@ def plan_fingerprint(plan) -> str:
     memoized on the plan object (id-stable: repeat lookups are O(1))."""
     fp = getattr(plan, "_fingerprint", None)
     if fp is None:
-        h = hashlib.sha1(f"{plan.model}/{plan.p}".encode())
+        h = hashlib.sha1(f"{plan.model}/{plan.p}/{plan.blocks}".encode())
         for tag, group in (
             ("own", plan.ownership),
             ("loc", plan.local_ids),

@@ -44,6 +44,8 @@ from collections import Counter, OrderedDict
 
 import numpy as np
 
+from repro.distributed.plan_ir import SCALAR_BLOCKS
+from repro.distributed.registry import checked_blocks
 from repro.resilience import FaultPolicy, retry_call
 from repro.sparse.structure import structure_and_values, structure_fingerprint
 
@@ -131,6 +133,7 @@ class SpGEMMSession:
         warm_drift_limit: float = 0.5,
         max_entries: int = 8,
         dtype=np.float32,
+        blocks=None,
     ):
         self.p = p
         self.model = model
@@ -142,6 +145,12 @@ class SpGEMMSession:
         self.warm_drift_limit = warm_drift_limit
         self.max_entries = max_entries
         self.dtype = np.dtype(dtype)
+        # ((r, k), (k, c)): operands of r x k and k x c blocks, part of every
+        # key, plan and store entry.  Only the fine family runs them, so a
+        # blocked session never downgrades out of it.
+        self.blocks = checked_blocks(model, blocks)
+        if self.blocks != SCALAR_BLOCKS:
+            self.policy = dataclasses.replace(self.policy, model_chain=())
         self.events: list[SessionEvent] = []
         self._pool: OrderedDict[str, _Entry] = OrderedDict()
         self._last: _Entry | None = None
@@ -212,6 +221,8 @@ class SpGEMMSession:
             f"{structure_fingerprint(a_s)}/{structure_fingerprint(b_s)}"
             f"/p={self.p}/model={self.model}/eps={self.eps!r}/seed={self.seed}"
         )
+        if self.blocks != SCALAR_BLOCKS:
+            ident += f"/blocks={self.blocks}"
         return hashlib.sha1(ident.encode()).hexdigest()
 
     def _admit(self, entry: _Entry) -> None:
@@ -298,6 +309,7 @@ class SpGEMMSession:
                         eps=self.eps,
                         seed=self.seed,
                         engine=eng,
+                        blocks=self.blocks,
                     )
                 return api._plan_one(
                     inst,
@@ -309,6 +321,7 @@ class SpGEMMSession:
                     engine=eng,
                     warm_start=warm_labels,
                     warm_drift_limit=self.warm_drift_limit,
+                    blocks=self.blocks,
                 )
 
             try:
@@ -447,7 +460,7 @@ class SpGEMMSession:
             return None
         meta = restored.meta
         model = meta.get("model")
-        if meta.get("p") != self.p or model is None:
+        if meta.get("p") != self.p or model is None or restored.plan.blocks != self.blocks:
             return None
         from repro.api import PlannedSpGEMM
         from repro.core.partition import PartitionResult

@@ -55,7 +55,13 @@ from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.compat import shard_map
 
-from repro.distributed.plan_ir import FinePlan, MonoCPlan, OuterPlan, RowwisePlan
+from repro.distributed.plan_ir import (
+    SCALAR_BLOCKS,
+    FinePlan,
+    MonoCPlan,
+    OuterPlan,
+    RowwisePlan,
+)
 
 
 def _take0(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -64,6 +70,12 @@ def _take0(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     rows = x[safe]
     mask = (idx >= 0).reshape((-1,) + (1,) * (x.ndim - 1))
     return jnp.where(mask, rows, 0)
+
+
+def _take_items(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """Gather last-axis columns of an items-major (items, N) table with -1
+    padding -> zero columns."""
+    return jnp.where(idx >= 0, x[:, jnp.maximum(idx, 0)], 0)
 
 
 def _own_tables(a_blk: jnp.ndarray, b_blk: jnp.ndarray):
@@ -376,7 +388,9 @@ def owned_c_values(c_local: jnp.ndarray, plan) -> np.ndarray:
     Works for every plan whose C lives in owned slots (fine, monoA, monoB,
     monoC, summa2d): ``plan.local_ids["c_nz"]`` names the C nonzero each
     slot holds.  The result is ``(nnz(C),)`` for scalar plans and
-    ``(nnz(C), b, b)`` for blocked ones; nothing is densified.  The
+    ``(nnz(C), b, b)`` for blocked ones (monoC's tiles), ``(nnz(C), r, c)``
+    for a fine-family plan over block operands, whose step returns each
+    device's slots flat, block after block; nothing is densified.  The
     slot -> canonical map is built on a plan's first unpack and memoized on
     the plan (``_c_order``); each product then pays one gather.  The host
     spans are ``repro.unpack.fetch`` (device-to-host copy),
@@ -385,6 +399,9 @@ def owned_c_values(c_local: jnp.ndarray, plan) -> np.ndarray:
     """
     with TraceAnnotation("repro.unpack.fetch"):
         c_np = np.asarray(c_local)
+    if plan.blocks != SCALAR_BLOCKS:
+        (r, _), (_, c) = plan.blocks
+        c_np = c_np.reshape(plan.p, -1, r, c)
     idx = _c_order(plan, c_np.shape[1])
     with TraceAnnotation("repro.unpack.reorder"):
         return _canonical_order(c_np, idx)
@@ -438,11 +455,14 @@ def unpack_monoC_result(
     ``c_structure`` is the block-grid structure of C (``inst.c`` of the plan
     instance); ``shape`` the padded dense shape (block-grid * block).
     """
-    vals = owned_c_values(c_local, plan)
-    b = vals.shape[-1]
-    gr, gc = shape[0] // b, shape[1] // b
+    return _dense_from_blocks(owned_c_values(c_local, plan), c_structure, shape)
+
+
+def _dense_from_blocks(vals: np.ndarray, c_structure, shape) -> np.ndarray:
+    """(nnz(C), r, c) block values on C's block structure -> dense ``shape``."""
+    r, c = vals.shape[1:]
     crow, ccol = c_structure.coo()
-    out = np.zeros((gr, gc, b, b), dtype=vals.dtype)
+    out = np.zeros((shape[0] // r, shape[1] // c, r, c), dtype=vals.dtype)
     out[crow, ccol] = vals
     return out.transpose(0, 2, 1, 3).reshape(shape)
 
@@ -450,14 +470,33 @@ def unpack_monoC_result(
 # ---------------------------------------------------------------------------
 # 3D fine-grained (Def. 3.1)
 # ---------------------------------------------------------------------------
+def _fine_tables(plan: FinePlan) -> tuple:
+    """The fine step's table arguments: the three routes' send slots, the
+    multiplication lists and the reduce/fold maps."""
+    return (
+        plan.routes["expand_a"].send_idx,
+        plan.routes["expand_b"].send_idx,
+        plan.routes["reduce_c"].send_idx,
+        plan.compute["pair_a"],
+        plan.compute["pair_b"],
+        plan.compute["pair_c"],
+        plan.compute["reduce_recv_slot"],
+        plan.compute["prod_to_owned"],
+    )
+
+
 def make_fine_step(plan: FinePlan, mesh: Mesh, axis: str = "x"):
     """Jit-compatible fine-grained executor core (expand-expand-reduce).
 
     Returns ``(fn, tables)``: ``fn(a_own, b_own, *tables) -> c_local`` over
     device-major packed scalar slot tables ((p, N_max)); ``tables`` are the
     three routes' send slots, the multiplication lists and the reduce/fold
-    maps.
+    maps.  A plan over block operands (``plan.blocks`` = ((r, k), (k, c)))
+    gets ``_make_blocked_fine_step``'s items-major step over the same
+    tables.
     """
+    if plan.blocks != SCALAR_BLOCKS:
+        return _make_blocked_fine_step(plan, mesh, axis)
     p = plan.p
     route_a = plan.routes["expand_a"]
     route_b = plan.routes["expand_b"]
@@ -465,16 +504,7 @@ def make_fine_step(plan: FinePlan, mesh: Mesh, axis: str = "x"):
     T_a, T_b, T_r = route_a.T, route_b.T, route_r.T
     R_max = plan.local_ids["c_prod"].shape[1]
     C_max = plan.local_ids["c_nz"].shape[1]
-    tables = (
-        route_a.send_idx,
-        route_b.send_idx,
-        route_r.send_idx,
-        plan.compute["pair_a"],
-        plan.compute["pair_b"],
-        plan.compute["pair_c"],
-        plan.compute["reduce_recv_slot"],
-        plan.compute["prod_to_owned"],
-    )
+    tables = _fine_tables(plan)
 
     def expand(own, send_idx_blk, T):
         # own: (N_max,); ship my cut-net scalars, receive the foreign ones
@@ -518,6 +548,86 @@ def make_fine_step(plan: FinePlan, mesh: Mesh, axis: str = "x"):
                 jnp.where(ok, recv.reshape(-1), 0)
             )
         return c[None]
+
+    shard = shard_map(
+        step,
+        mesh=mesh,
+        in_specs=(P(axis),) * 8 + (P(), P(axis)),
+        out_specs=P(axis),
+    )
+    return shard, tables
+
+
+def _make_blocked_fine_step(plan: FinePlan, mesh: Mesh, axis: str):
+    """The fine step over block operands, items-major.
+
+    Every table holds one row per item of a block and one column per slot:
+    A's (r*k, N_max), B's (k*c, N_max), the produced and owned C tables
+    (r*c, slots), so the nonzero index sits on the TPU's 128 lanes (an
+    (N, 3, 3) table would be padded to (8, 128) tiles in its last two
+    dimensions).  The multiplication lists are the scalar plan's, one entry
+    per block product: local compute gathers A's r*k and B's k*c items of
+    each, forms its r*c outputs as sums over k, and scatter-adds them into
+    the produced table; the routes ship whole blocks (columns)."""
+    p = plan.p
+    (r, k), (_, c) = plan.blocks
+    T_a, T_b, T_r = (plan.routes[n].T for n in ("expand_a", "expand_b", "reduce_c"))
+    R_max = plan.local_ids["c_prod"].shape[1]
+    C_max = plan.local_ids["c_nz"].shape[1]
+    tables = _fine_tables(plan)
+
+    def exchange(tab, send_idx_blk, T):
+        # ship the columns named by send_idx_blk (p, T) to each device; the
+        # arrivals come back (items, p * T), source-major
+        buf = _take_items(tab, send_idx_blk.reshape(-1)).reshape(-1, p, T)
+        recv = jax.lax.all_to_all(
+            buf[None], axis, split_axis=2, concat_axis=2, tiled=False
+        )[0]
+        return recv.reshape(-1, p * T)
+
+    def expand(own, send_idx_blk, T):
+        zero = jnp.zeros((own.shape[0], 1), own.dtype)
+        return jnp.concatenate([own, exchange(own, send_idx_blk, T), zero], 1)
+
+    def products(a_tab, b_tab, pa, pb):
+        # one gather of each item row, then c_ij = sum_q a_iq * b_qj for
+        # every multiplication at once: (r*c, M)
+        a_g = a_tab[:, pa]
+        b_g = b_tab[:, pb]
+        rows = []
+        for i in range(r):
+            for j in range(c):
+                acc = a_g[i * k] * b_g[j]
+                for q in range(1, k):
+                    acc = acc + a_g[i * k + q] * b_g[q * c + j]
+                rows.append(acc)
+        return jnp.stack(rows)
+
+    def step(a_blk, b_blk, sa_, sb_, sr_, pa_, pb_, pc_, recv_slot_all, prod_own_):
+        a_own, b_own = _own_tables(a_blk, b_blk)
+        with jax.named_scope("repro.expand_a"):
+            a_tab = expand(a_own, sa_[0], T_a)
+        with jax.named_scope("repro.expand_b"):
+            b_tab = expand(b_own, sb_[0], T_b)
+        with jax.named_scope("repro.local"):
+            zero = jnp.zeros((r * c, max(R_max, C_max) + 1), a_tab.dtype)
+            # the plan sorts each device's list by produced slot (padding,
+            # the garbage slot R_max, last)
+            partial = zero[:, : R_max + 1].at[:, pc_[0]].add(
+                products(a_tab, b_tab, pa_[0], pb_[0]), indices_are_sorted=True
+            )
+            own_map = prod_own_[0]
+            okp = own_map >= 0
+            c_tab = zero[:, : C_max + 1].at[:, jnp.where(okp, own_map, C_max)].add(
+                jnp.where(okp, partial[:, :R_max], 0)
+            )
+        with jax.named_scope("repro.reduce_c"):
+            recv = exchange(partial, sr_[0], T_r)
+            me = jax.lax.axis_index(axis)
+            slots = recv_slot_all[:, me].reshape(-1)
+            ok = slots >= 0
+            c_tab = c_tab.at[:, jnp.where(ok, slots, C_max)].add(jnp.where(ok, recv, 0))
+        return c_tab[None]
 
     shard = shard_map(
         step,
@@ -584,6 +694,8 @@ def unpack_fine_result(
 ) -> np.ndarray:
     """Scatter device-major owned-C slot values back to a dense array."""
     vals = owned_c_values(c_local, plan)
+    if vals.ndim == 3:
+        return _dense_from_blocks(vals, c_structure, shape)
     crow, ccol = c_structure.coo()
     out = np.zeros(shape, dtype=vals.dtype)
     out[crow, ccol] = vals

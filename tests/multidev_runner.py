@@ -260,6 +260,45 @@ def case_fine_identity_partition():
     print("OK fine_identity")
 
 
+def case_fine_blocked():
+    """Block operands (A 3x3, B 3x6 blocks) on the fine executor family at
+    p=N_DEV: C equals the float64 product of the scalar expansions, and the
+    route words (items times the block areas 9, 18, 18) equal the blocked
+    hypergraph's connectivity."""
+    import scipy.sparse as sp
+
+    import repro
+    from repro.distributed.plan_ir import measured_route_words
+
+    blocks = ((3, 3), (3, 6))
+    rng = np.random.default_rng(17)
+    a_s = random_structure(24, 20, 0.2, rng)
+    b_s = random_structure(20, 22, 0.2, rng)
+    a_vals = rng.standard_normal((a_s.nnz, 3, 3), dtype=np.float32)
+    b_vals = rng.standard_normal((b_s.nnz, 3, 6), dtype=np.float32)
+
+    def expand(s, vals, block):
+        return sp.bsr_matrix(
+            (vals.astype(np.float64), s.csr.indices, s.csr.indptr),
+            shape=(s.shape[0] * block[0], s.shape[1] * block[1]),
+        ).toarray()
+
+    a64, b64 = expand(a_s, a_vals, blocks[0]), expand(b_s, b_vals, blocks[1])
+    want, scale = a64 @ b64, np.abs(a64) @ np.abs(b64)
+    for model in ("fine", "monoA", "monoB"):
+        handle = repro.plan(a_s, b_s, p=N_DEV, model=model, blocks=blocks)
+        plan = handle.execution_plan
+        got = handle(a_vals, b_vals)
+        err = (np.abs(got - want) / np.maximum(scale, np.finfo(float).tiny)).max()
+        assert err <= 1e-4, (model, err)
+        report = handle.cost_report()
+        items = {n: int((r.recv_key >= 0).sum()) for n, r in plan.routes.items()}
+        weighted = 9 * items["expand_a"] + 18 * items["expand_b"] + 18 * items["reduce_c"]
+        assert report["predicted_words"] == measured_route_words(plan) == weighted > 0, (
+            model, report, items)
+        print("OK fine_blocked %s p=%d words=%d" % (model, N_DEV, weighted))
+
+
 def case_select():
     """End-to-end model selection: sweep every model on a small instance,
     execute the plans that have executors, measured == predicted for the

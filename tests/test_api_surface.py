@@ -42,7 +42,7 @@ def test_plan_signature_is_pinned():
     sig = inspect.signature(repro.plan)
     assert list(sig.parameters) == [
         "A", "B", "p", "model", "eps", "seed", "name", "include_nz", "engine",
-        "coarsen",
+        "coarsen", "blocks",
     ]
     defaults = {
         k: v.default
@@ -59,6 +59,7 @@ def test_plan_signature_is_pinned():
         "include_nz": False,
         "engine": "flat",
         "coarsen": "auto",
+        "blocks": None,
     }
 
 

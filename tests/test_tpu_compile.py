@@ -125,3 +125,58 @@ def test_monoC_pallas_step_compiles_for_four_tpus(topo, amg_small):
     )
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-to-all" in text
+
+
+def test_blocked_fine_step_compiles_for_four_tpus(topo, amg_small):
+    """Items-major tables of 3x3 and 3x6 blocks, the routes shipping whole
+    columns."""
+    from repro.distributed.spgemm_exec import make_fine_step
+
+    plan = repro.plan(amg_small, p=4, model="fine", blocks=((3, 3), (3, 6))).execution_plan
+    mesh = Mesh(np.array(topo.devices[:4]), ("x",))
+    compiled = _step_compiled(
+        make_fine_step(plan, mesh),
+        mesh,
+        "x",
+        (4, 9, plan.local_ids["a_nz"].shape[1]),
+        (4, 18, plan.local_ids["b_nz"].shape[1]),
+    )
+    assert "all-to-all" in compiled.as_text()
+
+
+def test_blocked_fine_runner_fits_one_chip_at_72(one_chip):
+    """The 72^3 elasticity cell's whole program (layout change, value
+    scatter, step) at its real shapes fits one v5e's 16 GiB.  Only the
+    shapes of the p=1 plan's tables matter, so the plan is built from
+    them."""
+    from types import SimpleNamespace
+
+    from repro.distributed.plan_ir import FinePlan, Route
+    from repro.distributed.registry import _fine_runner
+
+    n_a, n_b, n_c, n_mult = 9800344, 1643032, 4410944, 43614208
+    pad = np.full((1, 1, 1), -1)
+    plan = FinePlan(
+        model="fine",
+        p=1,
+        ownership={"a_nz": np.zeros(n_a), "b_nz": np.zeros(n_b)},
+        local_ids={"a_nz": np.arange(n_a)[None], "b_nz": np.arange(n_b)[None],
+                   "c_nz": np.zeros((1, n_c)), "c_prod": np.zeros((1, n_c))},
+        routes={r: Route("A", pad, pad, 0, 0) for r in ("expand_a", "expand_b", "reduce_c")},
+        compute={"pair_a": np.zeros((1, n_mult)), "pair_b": np.zeros((1, n_mult)),
+                 "pair_c": np.zeros((1, n_mult)), "reduce_recv_slot": pad,
+                 "prod_to_owned": np.zeros((1, n_c))},
+        blocks=((3, 3), (3, 6)),
+    )
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("x",))
+    setup = _fine_runner(plan, SimpleNamespace(nnz=n_a, shape=(1, 1)),
+                         SimpleNamespace(nnz=n_b, shape=(1, 1)), mesh, dtype=np.float32,
+                         block=1, backend=None, axis="x", axes=("x", "y"))
+    compiled = jax.jit(setup.run).lower(
+        _sds(setup.a_shape, jnp.float32, one_chip),
+        _sds(setup.b_shape, jnp.float32, one_chip),
+        *(_sds(np.shape(t), jnp.int32, one_chip) for t in setup.tables),
+    ).compile()
+    mem = compiled.memory_analysis()
+    total = mem.temp_size_in_bytes + mem.argument_size_in_bytes + mem.output_size_in_bytes
+    assert total < 14 * 2**30, total
