@@ -33,6 +33,7 @@ the speedup.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 
@@ -610,16 +611,21 @@ class FinePlan(ExecutionPlan):
     Per-device state the executor mirrors:
 
     - operand slot tables ``[owned | received | zero]`` (as monoC);
-    - a *produced-C* table: slot r on device d accumulates d's partial sum
-      for the r-th distinct C nonzero d's multiplications contribute to
+    - *produced-C* slots: slot r on device d stands for d's partial sum for
+      the r-th distinct C nonzero d's multiplications contribute to
       (``local_ids["c_prod"]``), plus a trailing garbage slot for padding;
     - ``compute["pair_*"]``: padded (p, P_max) multiplication lists in slot
-      coordinates — pair_a/pair_b index the operand tables, pair_c the
-      produced table;
+      coordinates — pair_a/pair_b index the operand tables, pair_c names
+      the produced slot;
     - ``compute["reduce_recv_slot"]``: (p, p, T_r) owned-C slot each arriving
       reduce item folds into (-1 padding);
     - ``compute["prod_to_owned"]``: (p, R_max) owned-C slot of each produced
       slot when the producer already owns that C nonzero (-1 otherwise).
+
+    Each device's list is sorted by produced slot (padding, the garbage
+    slot R_max, last), so every produced slot's products are one run:
+    ``prod_heads`` gives each run's first position and ``segment_passes``
+    the passes a segmented sum over the runs makes.
     """
 
     @property
@@ -655,6 +661,35 @@ class FinePlan(ExecutionPlan):
     def n_c_slots(self) -> int:
         """Owned-C slots incl. the trailing garbage slot padded arrivals hit."""
         return self.local_ids["c_nz"].shape[1] + 1
+
+    @functools.cached_property
+    def prod_heads(self) -> np.ndarray:
+        """(p, R_max): position in the device's multiplication list of the
+        first product of each produced slot (-1 for padding slots)."""
+        pc = self.compute["pair_c"]
+        if (np.diff(pc, axis=1) < 0).any():
+            raise ValueError("multiplication lists are not sorted by produced slot")
+        R_max = self.n_prod_slots - 1
+        first = np.ones(pc.shape, dtype=bool)
+        first[:, 1:] = pc[:, 1:] != pc[:, :-1]
+        dev, pos = np.nonzero(first & (pc < R_max))
+        heads = np.full((self.p, R_max), -1, dtype=np.int64)
+        heads[dev, pc[dev, pos]] = pos
+        return heads
+
+    @functools.cached_property
+    def segment_passes(self) -> int:
+        """Shift-and-add passes of the fine step's segmented sum:
+        ceil(log2) of the longest run of one produced slot in a device's
+        multiplication list, 0 when every run has length 1."""
+        n_real = (self.compute["pair_c"] < self.n_prod_slots - 1).sum(axis=1)
+        # a device's runs follow one another from position 0 up to its
+        # padding, so each ends where the next one, or the padding, begins
+        longest = max(
+            (np.diff(h[h >= 0], append=n).max(initial=1) for h, n in zip(self.prod_heads, n_real)),
+            default=1,
+        )
+        return (int(longest) - 1).bit_length()
 
 
 def build_fine_plan(

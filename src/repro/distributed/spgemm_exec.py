@@ -17,10 +17,11 @@ These realize the paper's algorithm classes as compiled JAX programs:
 - ``fine_spgemm``: 3D fine-grained (Def. 3.1) — an arbitrary flop-level
   partition drives an expand-expand-reduce schedule: two padded
   ``all_to_all`` phases ship the cut A- and B-nets, each device evaluates
-  exactly its multiplication vertices into a produced-partial-C table, and a
-  third ``all_to_all`` (the cut C-nets) folds foreign partials into each
-  C nonzero's owner.  Every word any phase moves is one (cut net, part)
-  pair of the partition — the connectivity metric made executable.
+  exactly its multiplication vertices and sums them per produced C
+  nonzero, and a third ``all_to_all`` (the cut C-nets) folds foreign
+  partials into each C nonzero's owner.  Every word any phase moves is one
+  (cut net, part) pair of the partition — the connectivity metric made
+  executable.
 - ``spsumma``: the sparsity-independent 2D baseline (Buluç–Gilbert SpSUMMA):
   stationary-C with A broadcast along mesh rows and B along mesh columns.
 
@@ -76,6 +77,25 @@ def _take_items(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """Gather last-axis columns of an items-major (items, N) table with -1
     padding -> zero columns."""
     return jnp.where(idx >= 0, x[:, jnp.maximum(idx, 0)], 0)
+
+
+def _segment_heads(vals: jnp.ndarray, seg: jnp.ndarray, n_pass: int) -> jnp.ndarray:
+    """Sum each run of equal ``seg`` entries along ``vals``'s last axis into
+    the run's first position: a segmented scan from the right whose pass t
+    adds the value ``d = 2**t`` places ahead where it lies in the same run.
+    ``n_pass`` passes sum runs of up to ``2**n_pass``; ``seg`` (M,) must
+    hold each run contiguously.
+
+    Each pass reads ahead through an offset slice.  Reading behind would
+    take a prefix slice, which the TPU compiler turns into a view of the
+    very buffer the pass then overwrites in place; on a v5e the blocked
+    step summed wrong that way."""
+    for t in range(n_pass):
+        d = 1 << t
+        same = jnp.pad(seg[:-d] == seg[d:], (0, d))
+        ahead = jnp.pad(vals[..., d:], [(0, 0)] * (vals.ndim - 1) + [(0, d)])
+        vals = vals + jnp.where(same, ahead, 0)
+    return vals
 
 
 def _own_tables(a_blk: jnp.ndarray, b_blk: jnp.ndarray):
@@ -471,17 +491,33 @@ def _dense_from_blocks(vals: np.ndarray, c_structure, shape) -> np.ndarray:
 # 3D fine-grained (Def. 3.1)
 # ---------------------------------------------------------------------------
 def _fine_tables(plan: FinePlan) -> tuple:
-    """The fine step's table arguments: the three routes' send slots, the
-    multiplication lists and the reduce/fold maps."""
+    """The fine step's table arguments: the expand routes' send slots, the
+    multiplication lists, the reduce's arrival slots and two maps into the
+    segmented sum of the products (``_segment_heads``): ``send_head``
+    (p, p, T_r), the position of each reduce item's sum (the route's send
+    slots composed with ``plan.prod_heads``), and ``own_head``
+    (p, C_max + 1), the position of the sum each owned C slot takes
+    (``prod_to_owned`` inverted and composed the same way; -1 where the
+    device produces nothing for the slot, and in the trailing garbage
+    slot)."""
+    p = plan.p
+    heads = plan.prod_heads
+    send = plan.routes["reduce_c"].send_idx
+    dev = np.arange(p)[:, None, None]
+    send_head = np.where(send >= 0, heads[dev, np.maximum(send, 0)], -1)
+    prod_own = plan.compute["prod_to_owned"]
+    own_head = np.full((p, plan.n_c_slots), -1, dtype=np.int64)
+    d, s = np.nonzero(prod_own >= 0)
+    own_head[d, prod_own[d, s]] = heads[d, s]
     return (
         plan.routes["expand_a"].send_idx,
         plan.routes["expand_b"].send_idx,
-        plan.routes["reduce_c"].send_idx,
+        send_head,
         plan.compute["pair_a"],
         plan.compute["pair_b"],
         plan.compute["pair_c"],
         plan.compute["reduce_recv_slot"],
-        plan.compute["prod_to_owned"],
+        own_head,
     )
 
 
@@ -489,11 +525,12 @@ def make_fine_step(plan: FinePlan, mesh: Mesh, axis: str = "x"):
     """Jit-compatible fine-grained executor core (expand-expand-reduce).
 
     Returns ``(fn, tables)``: ``fn(a_own, b_own, *tables) -> c_local`` over
-    device-major packed scalar slot tables ((p, N_max)); ``tables`` are the
-    three routes' send slots, the multiplication lists and the reduce/fold
-    maps.  A plan over block operands (``plan.blocks`` = ((r, k), (k, c)))
-    gets ``_make_blocked_fine_step``'s items-major step over the same
-    tables.
+    device-major packed scalar slot tables ((p, N_max)); ``tables`` are
+    ``_fine_tables``'s.  Local compute sums each device's products per
+    produced slot by a segmented scan (``plan.segment_passes`` passes), and
+    the owned C table and the reduce's send buffer gather the run heads.  A
+    plan over block operands (``plan.blocks`` = ((r, k), (k, c))) gets
+    ``_make_blocked_fine_step``'s items-major step over the same tables.
     """
     if plan.blocks != SCALAR_BLOCKS:
         return _make_blocked_fine_step(plan, mesh, axis)
@@ -502,8 +539,8 @@ def make_fine_step(plan: FinePlan, mesh: Mesh, axis: str = "x"):
     route_b = plan.routes["expand_b"]
     route_r = plan.routes["reduce_c"]
     T_a, T_b, T_r = route_a.T, route_b.T, route_r.T
-    R_max = plan.local_ids["c_prod"].shape[1]
     C_max = plan.local_ids["c_nz"].shape[1]
+    n_pass = plan.segment_passes
     tables = _fine_tables(plan)
 
     def expand(own, send_idx_blk, T):
@@ -515,29 +552,21 @@ def make_fine_step(plan: FinePlan, mesh: Mesh, axis: str = "x"):
         zero = jnp.zeros((1,), own.dtype)
         return jnp.concatenate([own, recv.reshape(p * T), zero], 0)
 
-    def step(a_blk, b_blk, sa_, sb_, sr_, pa_, pb_, pc_, recv_slot_all, prod_own_):
+    def step(a_blk, b_blk, sa_, sb_, send_head_, pa_, pb_, pc_, recv_slot_all, own_head_):
         a_own, b_own = _own_tables(a_blk, b_blk)
         with jax.named_scope("repro.expand_a"):
             a_tab = expand(a_own, sa_[0], T_a)
         with jax.named_scope("repro.expand_b"):
             b_tab = expand(b_own, sb_[0], T_b)
         with jax.named_scope("repro.local"):
-            # one zero table, sliced for the partial and the owned-C tables:
-            # the compiler merges equal constants (R_max == C_max at p=1),
-            # and a merged one keeps no scope
-            zero = jnp.zeros((max(R_max, C_max) + 1,), a_tab.dtype)
-            # exactly this device's multiplication vertices
-            prods = a_tab[pa_[0]] * b_tab[pb_[0]]
-            partial = zero[: R_max + 1].at[pc_[0]].add(prods)
-            # partials this device both produced and owns fold locally
-            own_map = prod_own_[0]
-            okp = own_map >= 0
-            c = zero[: C_max + 1].at[jnp.where(okp, own_map, C_max)].add(
-                jnp.where(okp, partial[:R_max], 0)
-            )
+            # exactly this device's multiplication vertices, summed per
+            # produced slot at the head of the slot's run
+            sums = _segment_heads(a_tab[pa_[0]] * b_tab[pb_[0]], pc_[0], n_pass)
+            # partials this device both produced and owns
+            c = _take0(sums, own_head_[0])
         with jax.named_scope("repro.reduce_c"):
             # ship foreign partials to their C owners and fold them in
-            buf = _take0(partial, sr_[0].reshape(-1)).reshape(p, T_r)
+            buf = _take0(sums, send_head_[0].reshape(-1)).reshape(p, T_r)
             recv = jax.lax.all_to_all(
                 buf[None], axis, split_axis=1, concat_axis=1, tiled=False
             )[0]
@@ -567,13 +596,14 @@ def _make_blocked_fine_step(plan: FinePlan, mesh: Mesh, axis: str):
     (N, 3, 3) table would be padded to (8, 128) tiles in its last two
     dimensions).  The multiplication lists are the scalar plan's, one entry
     per block product: local compute gathers A's r*k and B's k*c items of
-    each, forms its r*c outputs as sums over k, and scatter-adds them into
-    the produced table; the routes ship whole blocks (columns)."""
+    each, forms its r*c outputs as sums over k, sums them per produced
+    slot by the segmented scan and gathers the owned C columns from the
+    run heads; the routes ship whole blocks (columns)."""
     p = plan.p
     (r, k), (_, c) = plan.blocks
     T_a, T_b, T_r = (plan.routes[n].T for n in ("expand_a", "expand_b", "reduce_c"))
-    R_max = plan.local_ids["c_prod"].shape[1]
     C_max = plan.local_ids["c_nz"].shape[1]
+    n_pass = plan.segment_passes
     tables = _fine_tables(plan)
 
     def exchange(tab, send_idx_blk, T):
@@ -603,26 +633,17 @@ def _make_blocked_fine_step(plan: FinePlan, mesh: Mesh, axis: str):
                 rows.append(acc)
         return jnp.stack(rows)
 
-    def step(a_blk, b_blk, sa_, sb_, sr_, pa_, pb_, pc_, recv_slot_all, prod_own_):
+    def step(a_blk, b_blk, sa_, sb_, send_head_, pa_, pb_, pc_, recv_slot_all, own_head_):
         a_own, b_own = _own_tables(a_blk, b_blk)
         with jax.named_scope("repro.expand_a"):
             a_tab = expand(a_own, sa_[0], T_a)
         with jax.named_scope("repro.expand_b"):
             b_tab = expand(b_own, sb_[0], T_b)
         with jax.named_scope("repro.local"):
-            zero = jnp.zeros((r * c, max(R_max, C_max) + 1), a_tab.dtype)
-            # the plan sorts each device's list by produced slot (padding,
-            # the garbage slot R_max, last)
-            partial = zero[:, : R_max + 1].at[:, pc_[0]].add(
-                products(a_tab, b_tab, pa_[0], pb_[0]), indices_are_sorted=True
-            )
-            own_map = prod_own_[0]
-            okp = own_map >= 0
-            c_tab = zero[:, : C_max + 1].at[:, jnp.where(okp, own_map, C_max)].add(
-                jnp.where(okp, partial[:, :R_max], 0)
-            )
+            sums = _segment_heads(products(a_tab, b_tab, pa_[0], pb_[0]), pc_[0], n_pass)
+            c_tab = _take_items(sums, own_head_[0])
         with jax.named_scope("repro.reduce_c"):
-            recv = exchange(partial, sr_[0], T_r)
+            recv = exchange(sums, send_head_[0], T_r)
             me = jax.lax.axis_index(axis)
             slots = recv_slot_all[:, me].reshape(-1)
             ok = slots >= 0
@@ -656,11 +677,12 @@ def fine_spgemm(
        multiplications read (slot table ``[owned | received | zero]``);
     2. B-expand: same for B;
     3. local compute: the device's multiplication list is two gathers, an
-       elementwise product, and a segment-add into its produced-partial-C
-       table — exactly its multiplication vertices, no more;
+       elementwise product, and a segmented sum over its runs of one
+       produced slot — exactly its multiplication vertices, no more;
     4. C-reduce: foreign partials ship to each C nonzero's owner and fold
-       into the owned-C table; partials the producer already owns fold
-       locally through ``prod_to_owned``.
+       into the owned-C table; partials the producer already owns are
+       gathered from their run heads (``prod_to_owned`` composed with
+       ``plan.prod_heads``).
 
     ``a`` / ``b`` may each be a dense array, a scipy sparse matrix, or an
     ``(SparseStructure, values)`` pair — callers that already hold sparse

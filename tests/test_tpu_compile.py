@@ -10,6 +10,7 @@ loads the TPU library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -144,17 +145,24 @@ def test_blocked_fine_step_compiles_for_four_tpus(topo, amg_small):
     assert "all-to-all" in compiled.as_text()
 
 
-def test_blocked_fine_runner_fits_one_chip_at_72(one_chip):
+@pytest.fixture(scope="module")
+def elastic72(one_chip):
     """The 72^3 elasticity cell's whole program (layout change, value
-    scatter, step) at its real shapes fits one v5e's 16 GiB.  Only the
-    shapes of the p=1 plan's tables matter, so the plan is built from
-    them."""
+    scatter, step) at its real shapes, compiled for one v5e.  Only the
+    shapes of the p=1 plan's tables and its run structure matter, so the
+    plan is built from them: each of the n_c produced slots a run of 9 to
+    27 block products (the 27-point stencil's longest is 27, so the step
+    makes 5 passes), folding into the owned C slot of the same rank."""
     from types import SimpleNamespace
 
     from repro.distributed.plan_ir import FinePlan, Route
     from repro.distributed.registry import _fine_runner
 
     n_a, n_b, n_c, n_mult = 9800344, 1643032, 4410944, 43614208
+    runs = np.full(n_c, 9)
+    extra, rest = divmod(n_mult - runs.sum(), 18)
+    runs[:extra] += 18
+    runs[extra] += rest
     pad = np.full((1, 1, 1), -1)
     plan = FinePlan(
         model="fine",
@@ -164,19 +172,49 @@ def test_blocked_fine_runner_fits_one_chip_at_72(one_chip):
                    "c_nz": np.zeros((1, n_c)), "c_prod": np.zeros((1, n_c))},
         routes={r: Route("A", pad, pad, 0, 0) for r in ("expand_a", "expand_b", "reduce_c")},
         compute={"pair_a": np.zeros((1, n_mult)), "pair_b": np.zeros((1, n_mult)),
-                 "pair_c": np.zeros((1, n_mult)), "reduce_recv_slot": pad,
-                 "prod_to_owned": np.zeros((1, n_c))},
+                 "pair_c": np.repeat(np.arange(n_c), runs)[None], "reduce_recv_slot": pad,
+                 "prod_to_owned": np.arange(n_c)[None]},
         blocks=((3, 3), (3, 6)),
     )
+    assert plan.segment_passes == 5
     mesh = Mesh(np.array(list(one_chip.device_set)), ("x",))
     setup = _fine_runner(plan, SimpleNamespace(nnz=n_a, shape=(1, 1)),
                          SimpleNamespace(nnz=n_b, shape=(1, 1)), mesh, dtype=np.float32,
                          block=1, backend=None, axis="x", axes=("x", "y"))
-    compiled = jax.jit(setup.run).lower(
+    return jax.jit(setup.run).lower(
         _sds(setup.a_shape, jnp.float32, one_chip),
         _sds(setup.b_shape, jnp.float32, one_chip),
         *(_sds(np.shape(t), jnp.int32, one_chip) for t in setup.tables),
     ).compile()
-    mem = compiled.memory_analysis()
+
+
+def test_blocked_fine_runner_fits_one_chip_at_72(elastic72):
+    """The elasticity cell's program fits one v5e's 16 GiB."""
+    mem = elastic72.memory_analysis()
     total = mem.temp_size_in_bytes + mem.argument_size_in_bytes + mem.output_size_in_bytes
     assert total < 14 * 2**30, total
+
+
+def test_segment_sum_reads_no_view_of_a_buffer_it_overwrites(elastic72):
+    """No fusion of local compute takes both a buffer and a bitcast view of
+    it.  A fusion may write its result over a buffer it reads elementwise,
+    and a view read at another index would then see values the fusion has
+    already overwritten: a shift-and-add pass that reads behind through a
+    prefix slice compiles to that, and summed wrong on a v5e."""
+    instr = re.compile(r"^\s*(?:ROOT )?%(\S+) = \S+ ([a-z][\w\-]*)\(([^)]*)\)")
+    text = elastic72.as_text()
+    lines = text.splitlines()
+    start = next(i for i, line in enumerate(lines) if line.startswith("ENTRY"))
+    ops = {}
+    for line in lines[start + 1:]:
+        m = instr.match(line)
+        if m:
+            args = [a.strip().lstrip("%") for a in m[3].split(",") if a.strip()]
+            ops[m[1]] = (m[2], args, "repro.local" in line)
+    fusions = [(n, args) for n, (op, args, local) in ops.items() if op == "fusion" and local]
+    assert fusions
+    viewing = {
+        name: arg for name, args in fusions for arg in args
+        if ops.get(arg, ("",))[0] == "bitcast" and ops[arg][1][0] in args
+    }
+    assert not viewing, viewing
