@@ -5,9 +5,9 @@ seven (the full registry is executable) to plans, count the words their
 routing tables ship, and — when the process owns enough devices — run the
 executors against the dense oracle.  The suite's acceptance assertion is
 the paper's central claim made executable: for the replicated-free plans
-(fine-grained and the monochrome family) the measured words equal the
-connectivity metric the partitioner minimized, exactly; rowwise/columnwise
-match through their nnz-weighted useful words.
+(every model but rowwise) the measured words equal the
+connectivity metric the partitioner minimized, exactly; rowwise matches
+through its nnz-weighted useful words.
 
 Run standalone with forced host devices to exercise the executors:
 
@@ -23,11 +23,12 @@ import numpy as np
 
 from repro.distributed.registry import MODEL_SPECS
 
-# replicated-free plans: every shipped item is one nonzero payload, so the
-# words on the wire (minus padding) are exactly the connectivity cost
+# replicated-free plans: every shipped item is one nonzero payload (or one C
+# partial), so the words on the wire (minus padding) are exactly the
+# connectivity cost
 EXACT_MODELS = tuple(n for n, s in MODEL_SPECS.items() if s.measured == "exact")
-# outer's fold volume and rowwise's nnz-weighted useful words also reproduce
-# their models' predictions; asserted too, reported separately
+# rowwise's nnz-weighted useful words also reproduce its model's
+# prediction; asserted too, reported separately
 USEFUL_EXACT_MODELS = tuple(n for n, s in MODEL_SPECS.items() if s.measured == "useful")
 
 

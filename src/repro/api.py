@@ -242,8 +242,8 @@ class PlannedSpGEMM:
           path), item-weighted per the model's convention;
         - ``padded_words``: what the padded all_to_all slots move on the
           wire;
-        - ``planned_messages``: non-empty (src, dst) route cells + fold
-          messages — the alpha term next to the words' beta term;
+        - ``planned_messages``: non-empty (src, dst) route cells — the
+          alpha term next to the words' beta term;
         - ``bounds``: the classical eq. (1) lower bounds the paper compares
           against (local memory taken as 3 * nnz / p, the bench convention).
 

@@ -236,7 +236,6 @@ def _plan_classes():
         for cls in (
             plan_ir.ExecutionPlan,
             plan_ir.RowwisePlan,
-            plan_ir.OuterPlan,
             plan_ir.MonoCPlan,
             plan_ir.FinePlan,
             summa.SummaPlan,

@@ -24,7 +24,6 @@ __all__ = [
     "ExecutionPlan",
     "Route",
     "RowwisePlan",
-    "OuterPlan",
     "MonoCPlan",
     "FinePlan",
     "SummaPlan",
@@ -33,7 +32,6 @@ __all__ = [
     "executable_models",
     "get_spec",
     "build_rowwise_plan",
-    "build_outer_plan",
     "build_monoC_plan",
     "build_fine_plan",
     "build_volume_plan",
@@ -44,10 +42,8 @@ __all__ = [
     "build_summa_plan",
     "summa_words_ideal",
     "rowwise_spgemm",
-    "outer_product_spgemm",
     "monoC_spgemm",
     "fine_spgemm",
-    "spsumma",
 ]
 
 _HOME = {
@@ -55,12 +51,10 @@ _HOME = {
         "ExecutionPlan",
         "FinePlan",
         "MonoCPlan",
-        "OuterPlan",
         "Route",
         "RowwisePlan",
         "build_fine_plan",
         "build_monoC_plan",
-        "build_outer_plan",
         "build_rowwise_plan",
         "build_volume_plan",
         "derive_owner_from_pins",
@@ -84,9 +78,7 @@ _HOME = {
     "repro.distributed.spgemm_exec": (
         "fine_spgemm",
         "monoC_spgemm",
-        "outer_product_spgemm",
         "rowwise_spgemm",
-        "spsumma",
     ),
 }
 _EXPORT_TO_MODULE = {name: mod for mod, names in _HOME.items() for name in names}

@@ -16,8 +16,6 @@ once-warning shim covers old package-level imports).
   sends B row k from its owner to every device whose A-columns touch k — one
   transfer per (cut net, touched part) pair, i.e. volume = sum_n c(n) *
   (lambda(n) - 1) plus padding.  Realized as a single padded all_to_all.
-- outer-product: device d owns column set K_d of A and B-row set K_d; the
-  fold phase routes partial C rows to C's owner.
 - monochrome-C: device d owns a C-nonzero set; two expand phases ship the
   cut A- and B-nets, local compute streams BSR pair lists (see ``plan_ir``).
 
@@ -33,11 +31,9 @@ from repro.core.spgemm_models import SpGEMMInstance
 from repro.distributed.plan_ir import (  # noqa: F401  (re-exports)
     ExecutionPlan,
     MonoCPlan,
-    OuterPlan,
     Route,
     RowwisePlan,
     build_monoC_plan,
-    build_outer_plan,
     build_rowwise_plan,
 )
 
@@ -45,10 +41,8 @@ __all__ = [
     "ExecutionPlan",
     "Route",
     "RowwisePlan",
-    "OuterPlan",
     "MonoCPlan",
     "build_rowwise_plan",
-    "build_outer_plan",
     "build_monoC_plan",
     "build_rowwise_plan_loop",
 ]

@@ -15,8 +15,7 @@ is that prescription made concrete — the inspector output every executor in
   padding to the per-pair maximum so XLA sees static shapes.
 - **compute**: per-device local work lists (e.g. the (pair_a, pair_b,
   pair_c) block multiplication lists the BSR kernel streams through).
-- **stats**: scalar accounting that is not a routing table (fold volumes,
-  pair counts).
+- **stats**: scalar accounting that is not a routing table (pair counts).
 
 Ideal (connectivity-metric) vs padded volume is tracked per route so
 benchmarks can quantify executor overhead against the combinatorial cost
@@ -131,13 +130,11 @@ class ExecutionPlan:
 
     @property
     def comm_words_ideal(self) -> int:
-        route_words = sum(r.words_ideal for r in self.routes.values())
-        return int(route_words + self.stats.get("fold_words_ideal", 0))
+        return int(sum(r.words_ideal for r in self.routes.values()))
 
     @property
     def comm_words_padded(self) -> int:
-        route_words = sum(r.words_padded for r in self.routes.values())
-        return int(route_words + self.stats.get("fold_words_padded", 0))
+        return int(sum(r.words_padded for r in self.routes.values()))
 
     @property
     def padding_fraction(self) -> float:
@@ -156,8 +153,7 @@ def measured_route_words(
     that the cut and the schedule describe the same traffic.  ``item_words``
     optionally maps a route name to per-global-item useful word counts
     (e.g. nnz per shipped B row); routes not named count ``word_size`` per
-    item.  Fold-phase words tracked only in ``stats`` (the outer plan's
-    psum_scatter) are added as-is since that phase has no routing table.
+    item.
     """
     words = 0
     for name, r in plan.routes.items():
@@ -166,20 +162,15 @@ def measured_route_words(
             words += int(item_words[name][keys].sum())
         else:
             words += len(keys) * r.word_size
-    return int(words + plan.stats.get("fold_words_ideal", 0))
+    return int(words)
 
 
 def route_messages(plan: "ExecutionPlan") -> int:
     """Point-to-point messages the plan schedules: the number of non-empty
     ``(src, dst)`` cells across all routing tables (one padded all_to_all
-    lane per pair, however many items it carries), plus fold-phase messages
-    tracked only in ``stats`` (the outer plan's psum_scatter has no table —
-    ``build_outer_plan`` records ``p * (p - 1)`` there).  The alpha term of
-    the alpha-beta cost model, next to ``measured_route_words``'s beta."""
-    msgs = 0
-    for r in plan.routes.values():
-        msgs += int((r.recv_key >= 0).any(axis=2).sum())
-    return int(msgs + plan.stats.get("fold_messages", 0))
+    lane per pair, however many items it carries).  The alpha term of the
+    alpha-beta cost model, next to ``measured_route_words``'s beta."""
+    return int(sum(int((r.recv_key >= 0).any(axis=2).sum()) for r in plan.routes.values()))
 
 
 # ---------------------------------------------------------------------------
@@ -369,63 +360,6 @@ def build_rowwise_plan(
         ownership={"a_row": row_part, "b_row": b_part},
         local_ids={"a_row": local_rows, "b_row": local_b_rows},
         routes={"expand": route},
-    )
-
-
-# ---------------------------------------------------------------------------
-# 1D outer-product (Ex. 5.2)
-# ---------------------------------------------------------------------------
-class OuterPlan(ExecutionPlan):
-    """Outer-product plan: device d owns A-column/B-row set K_d; the fold
-    phase (psum_scatter over C row blocks) carries the C-net volume."""
-
-    @property
-    def k_part(self) -> np.ndarray:
-        return self.ownership["k"]
-
-    @property
-    def c_part(self) -> np.ndarray:
-        return self.ownership["c_row"]
-
-    @property
-    def local_ks(self) -> np.ndarray:
-        return self.local_ids["k"]
-
-
-def build_outer_plan(
-    inst: SpGEMMInstance,
-    k_part: np.ndarray,
-    p: int,
-    c_part: np.ndarray | None = None,
-) -> OuterPlan:
-    I, K, J = inst.shape
-    k_part = np.asarray(k_part, dtype=np.int64)
-    if c_part is None:
-        c_part = np.arange(I, dtype=np.int64) % p
-    else:
-        c_part = np.asarray(c_part, dtype=np.int64)
-    local_ks, _ = padded_id_lists(k_part, p)
-    # ideal fold volume: per C nonzero, (#distinct contributing k-parts - 1)
-    cpos = inst.mult_i * J + inst.mult_j
-    pair = np.unique(cpos * p + k_part[inst.mult_k])
-    lam = np.bincount(pair // p)
-    ideal = int(np.maximum(lam[lam > 0] - 1, 0).sum())
-    # realized fold: the executor's psum_scatter reduces dense padded C row
-    # blocks regardless of sparsity — every device ships (p-1)/p of I_pad * J
-    I_pad = (I + p - 1) // p * p
-    padded = I_pad * (p - 1) * J if p > 1 else 0
-    return OuterPlan(
-        model="outer",
-        p=p,
-        ownership={"k": k_part, "c_row": c_part},
-        local_ids={"k": local_ks},
-        stats={
-            "fold_words_ideal": ideal,
-            "fold_words_padded": padded,
-            # the psum_scatter is all-pairs: every device sends one C-row
-            # chunk to each of the other p - 1
-            "fold_messages": p * (p - 1) if p > 1 else 0,
-        },
     )
 
 

@@ -16,12 +16,14 @@ packing branches, and the ``EXECUTABLE`` tuple).  A ``ModelSpec`` bundles:
   function) the compile-once runtime AOT-compiles;
 - ``unpack`` / ``pack_values``: device-major shards <-> caller value layout;
 - ``item_words`` / ``measured``: how the plan's routed words relate to the
-  model's predicted words (exact, useful-exact, or volume-only).
+  model's predicted words (exact, or useful-exact for rowwise's B rows).
 
-All seven paper models are fully executable (lowerer + runner + unpacker);
-columnwise rides the rowwise machinery under ``C^T = B^T A^T``, and
-monoA/monoB lower through the fine plan with multiplications colocated
-with their stationary operand.  The registry also carries one entry that
+All seven paper models are fully executable (lowerer + runner + unpacker).
+Five run on the fine executor: columnwise, outer, monoA and monoB lower to
+fine plans whose multiplications sit on the part of their coarse vertex
+(B column j, inner index k, A or B nonzero), so only the net family the
+model cuts is shipped.  Rowwise keeps its dense row executor, and monoC
+its BSR step.  The registry also carries one entry that
 is *not* a hypergraph model: ``"summa2d"``, the sparsity-oblivious Sparse
 SUMMA baseline (``build is None`` — no hypergraph, no partition; the
 lowerer goes straight from the instance).  It is excluded from
@@ -46,7 +48,6 @@ from repro.distributed.plan_ir import (
     block_areas,
     build_fine_plan,
     build_monoC_plan,
-    build_outer_plan,
     build_rowwise_plan,
     derive_owner_from_pins,
 )
@@ -124,10 +125,6 @@ def _lower_rowwise(inst: SpGEMMInstance, parts: np.ndarray, p: int) -> Execution
     return build_rowwise_plan(inst, parts, p, b_part=b_part)
 
 
-def _lower_outer(inst: SpGEMMInstance, parts: np.ndarray, p: int) -> ExecutionPlan:
-    return build_outer_plan(inst, parts, p)
-
-
 def _lower_monoC(inst: SpGEMMInstance, parts: np.ndarray, p: int) -> ExecutionPlan:
     mult_dev = parts[inst.mult_c_pos]
     a_part = derive_owner_from_pins(inst.mult_a_pos, mult_dev, inst.a.nnz, p)
@@ -141,18 +138,29 @@ def _lower_fine(
     return build_fine_plan(inst, parts, p, blocks=blocks)
 
 
-def _transposed_instance(inst: SpGEMMInstance) -> SpGEMMInstance:
-    """The ``C^T = B^T A^T`` instance: columnwise of ``inst`` IS rowwise of
-    this (identical hypergraph — vertex ``v_j`` keeps its index, net
-    ``n^A_k`` keeps its pins and its ``nnz(A col k)`` cost)."""
-    return SpGEMMInstance(
-        inst.b.transpose(), inst.a.transpose(), name=f"{inst.name}^T"
-    )
-
-
+# columnwise and outer run on the fine executor: each multiplication goes to
+# the part of its 1D vertex (B column j or inner index k), the vertex's
+# operands stay there, and the other nonzeros take the lowest part that
+# needs them, so only the net family the model cuts is shipped and each cut
+# net of connectivity lambda costs lambda - 1 times its net cost in words.
 def _lower_columnwise(inst: SpGEMMInstance, parts: np.ndarray, p: int) -> ExecutionPlan:
-    plan = _lower_rowwise(_transposed_instance(inst), parts, p)
+    # only expand_a ships: a_ik to the parts of B row k's columns
+    parts = np.asarray(parts, dtype=np.int64)
+    _, b_col = inst.b.coo()
+    plan = build_fine_plan(inst, parts[inst.mult_j], p, b_part=parts[b_col])
     plan.model = "columnwise"
+    return plan
+
+
+def _lower_outer(inst: SpGEMMInstance, parts: np.ndarray, p: int) -> ExecutionPlan:
+    # only reduce_c ships: the partials of c_ij from the parts of its k's
+    parts = np.asarray(parts, dtype=np.int64)
+    _, a_col = inst.a.coo()
+    b_row, _ = inst.b.coo()
+    plan = build_fine_plan(
+        inst, parts[inst.mult_k], p, a_part=parts[a_col], b_part=parts[b_row]
+    )
+    plan.model = "outer"
     return plan
 
 
@@ -209,31 +217,6 @@ def _rowwise_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backe
         return step(a_local, b_local, *tables)
 
     tables = (rdev[ar], rslot[ar], ac, bdev[br], bslot[br], bc, *step_tables)
-    return RunnerSetup(run, (a_structure.nnz,), (b_structure.nnz,), (I, J), tables)
-
-
-def _outer_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend, axis, axes):
-    import jax.numpy as jnp
-
-    from repro.distributed import spgemm_exec as _exec
-
-    p = plan.p
-    I, K = a_structure.shape
-    _, J = b_structure.shape
-    if len(plan.ownership["k"]) != K:
-        raise ValueError("plan was built for different operand shapes")
-    ar, ac = a_structure.coo()
-    br, bc = b_structure.coo()
-    kdev, kslot = owner_slot(plan.local_ids["k"], K)
-    K_max = plan.local_ids["k"].shape[1]
-    step, step_tables = _exec.make_outer_step(plan, mesh, I, J, axis=axis)
-
-    def run(a_values, b_values, a_d, a_r, a_s, b_d, b_s, b_c, *tables):
-        a_cols = jnp.zeros((p, I, K_max), dtype).at[a_d, a_r, a_s].set(a_values)
-        b_rows = jnp.zeros((p, K_max, J), dtype).at[b_d, b_s, b_c].set(b_values)
-        return step(a_cols, b_rows, *tables)
-
-    tables = (kdev[ac], ar, kslot[ac], kdev[br], kslot[br], bc, *step_tables)
     return RunnerSetup(run, (a_structure.nnz,), (b_structure.nnz,), (I, J), tables)
 
 
@@ -336,30 +319,6 @@ def _fine_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend,
     )
 
 
-def _columnwise_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend, axis, axes):
-    # run rowwise on the transposed operands: the plan was lowered from the
-    # C^T = B^T A^T instance, so the inner runner sees A' = B^T, B' = A^T
-    # and produces C^T shards; values arrive in the *original* CSR orders
-    # and are permuted into the transposed (col-major) orders on device
-    a_t = b_structure.transpose()
-    b_t = a_structure.transpose()
-    inner = _rowwise_runner(
-        plan, a_t, b_t, mesh,
-        dtype=dtype, block=block, backend=backend, axis=axis, axes=axes,
-    )
-    ar, ac = a_structure.coo()
-    br, bc = b_structure.coo()
-
-    def run(a_values, b_values, perm_a, perm_b, *tables):
-        return inner.run(b_values[perm_b], a_values[perm_a], *tables)
-
-    I, _ = a_structure.shape
-    _, J = b_structure.shape
-    # CSR order of X^T enumerates X's nonzeros sorted by (col, row)
-    tables = (np.lexsort((ar, ac)), np.lexsort((br, bc)), *inner.tables)
-    return RunnerSetup(run, (a_structure.nnz,), (b_structure.nnz,), (I, J), tables)
-
-
 def _monoC_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend, axis, axes):
     # a_structure / b_structure are the BLOCK structures here; values are
     # (nnz, block, block) arrays in block CSR (= to_bsr) order
@@ -385,17 +344,6 @@ def _unpack_rowwise(c_local, plan, c_structure, shape):
     from repro.distributed.spgemm_exec import unpack_rowwise_result
 
     return unpack_rowwise_result(c_local, plan, shape[0])
-
-
-def _unpack_columnwise(c_local, plan, c_structure, shape):
-    from repro.distributed.spgemm_exec import unpack_rowwise_result
-
-    # the inner rowwise step computed C^T over J rows; transpose back
-    return unpack_rowwise_result(c_local, plan, shape[1]).T
-
-
-def _unpack_outer(c_local, plan, c_structure, shape):
-    return np.asarray(c_local).reshape(-1, shape[1])[: shape[0]]
 
 
 def _unpack_monoC(c_local, plan, c_structure, shape):
@@ -451,8 +399,9 @@ class ModelSpec:
 
     ``measured`` states how the plan's route-counted words relate to the
     model's prediction: "exact" (replicated-free plans — words on the
-    wire == the predicted words), "useful" (unit-cost prediction recovered
-    by nnz-weighting / fold accounting), or None (no executor).
+    wire == the predicted words), "useful" (rowwise: the unit-cost
+    prediction recovered by nnz-weighting the shipped B rows), or None (no
+    executor).
 
     ``build is None`` marks a partition-free baseline (summa2d): there is
     no hypergraph — the lowerer goes straight from the instance and the
@@ -555,21 +504,24 @@ MODEL_SPECS: dict[str, ModelSpec] = {
         family="1D",
         build=_build("columnwise"),
         lower=_lower_columnwise,
-        make_runner=_columnwise_runner,
-        unpack=_unpack_columnwise,
-        item_words=lambda inst: {"expand": inst.a.col_counts()},
-        measured="useful",
-        notes="rowwise under C^T = B^T A^T; ships whole A columns",
+        make_runner=_fine_runner,
+        unpack=_unpack_fine,
+        pack_values=_values_items,
+        needs_c_structure=True,
+        measured="exact",
+        notes="B column stationary; mults on their column's part, fine executor",
     ),
     "outer": ModelSpec(
         name="outer",
         family="1D",
         build=_build("outer"),
         lower=_lower_outer,
-        make_runner=_outer_runner,
-        unpack=_unpack_outer,
-        measured="useful",
-        notes="fold phase is psum_scatter; ideal fold words == prediction",
+        make_runner=_fine_runner,
+        unpack=_unpack_fine,
+        pack_values=_values_items,
+        needs_c_structure=True,
+        measured="exact",
+        notes="A column and B row k stationary; only C partials ship, fine executor",
     ),
     "monoA": ModelSpec(
         name="monoA",
@@ -637,10 +589,6 @@ MODEL_SPECS: dict[str, ModelSpec] = {
         notes="Sparse SUMMA (Buluc-Gilbert): sparsity-oblivious 2D baseline",
     ),
 }
-
-#: models whose partitions never lower to an executor (they still predict);
-#: empty since every paper model gained its executor, kept as API surface
-VOLUME_ONLY = tuple(n for n in MODELS if not MODEL_SPECS[n].executable)
 
 assert set(MODELS) <= set(MODEL_SPECS), "registry out of sync with core MODELS"
 

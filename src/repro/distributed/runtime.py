@@ -32,9 +32,9 @@ declarative source — this module only owns the model-agnostic machinery
 
 Value conventions (``__call__`` inputs):
 
-- rowwise / outer / fine: 1-D nonzero value vectors in the operands'
-  canonical CSR order (``SparseStructure`` order — what
-  ``structure_and_values`` returns);
+- rowwise and the fine family (fine, columnwise, outer, monoA, monoB): 1-D
+  nonzero value vectors in the operands' canonical CSR order
+  (``SparseStructure`` order — what ``structure_and_values`` returns);
 - monoC: (nnz, b, b) block-value arrays in the *block* structure's CSR
   order (``to_bsr(...).blocks`` order).  The ``repro.api`` front door hides
   this behind ``ModelSpec.pack_values``.

@@ -15,13 +15,13 @@ module closes the loop the models only predict:
    dense oracle, so "the words the cut prescribes" and "the words the
    program moves" are pinned to each other end to end.
 
-For replicated-free plans — fine-grained, monochrome-A/B/C, where every
-shipped item is a single nonzero payload — measured == predicted exactly.
-Row-wise (and columnwise, its ``C^T = B^T A^T`` mirror) ships whole dense
-rows, so its measured *useful* words match the unit-cost prediction while
-its wire words exceed the nnz-weighted cost; the sweep reports both so the
-gap is visible, as are the padded all_to_all overhead and the message
-count (``planned_messages``) for every model.
+For replicated-free plans — every model but rowwise, where each shipped
+item is a single nonzero payload or one C partial — measured == predicted
+exactly.  Row-wise ships whole dense rows, so its measured *useful* words
+match the unit-cost prediction while its wire words exceed the
+nnz-weighted cost; the sweep reports both so the gap is visible, as are
+the padded all_to_all overhead and the message count
+(``planned_messages``) for every model.
 
 Everything model-specific (which models lower, how routed words are
 weighted, what mesh/backend an executor wants) comes from the declarative

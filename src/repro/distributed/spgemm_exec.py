@@ -5,8 +5,6 @@ These realize the paper's algorithm classes as compiled JAX programs:
 - ``rowwise_spgemm``: 1D row-wise (Ex. 5.1) with a sparsity-dependent expand
   phase — one padded ``all_to_all`` whose payload is exactly the cut B-nets
   of the partition (plus padding), per ``RowwisePlan``.
-- ``outer_product_spgemm``: 1D outer-product (Ex. 5.2) — local rank-|K_d|
-  products and a fold phase realized as ``psum_scatter`` over C row blocks.
 - ``monoC_spgemm``: 2D sparsity-dependent monochrome-C (Ex. 5.4) — every
   C (block-)nonzero lives on one device; the cut A-nets and B-nets lower to
   two padded ``all_to_all`` expand phases on a 2D mesh, and local compute
@@ -21,9 +19,11 @@ These realize the paper's algorithm classes as compiled JAX programs:
   nonzero, and a third ``all_to_all`` (the cut C-nets) folds foreign
   partials into each C nonzero's owner.  Every word any phase moves is one
   (cut net, part) pair of the partition — the connectivity metric made
-  executable.
-- ``spsumma``: the sparsity-independent 2D baseline (Buluç–Gilbert SpSUMMA):
-  stationary-C with A broadcast along mesh rows and B along mesh columns.
+  executable.  The columnwise and outer models and monoA/monoB run this
+  executor too, as fine plans with their multiplications on the part of
+  their coarse vertex (``registry._lower_columnwise`` and its siblings).
+
+The sparsity-independent baseline, Sparse SUMMA, lives in ``summa.py``.
 
 Every sparsity-dependent executor consumes an ``ExecutionPlan``
 (``plan_ir``): ownership maps + padded routing tables + local work lists.
@@ -56,13 +56,7 @@ from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.compat import shard_map
 
-from repro.distributed.plan_ir import (
-    SCALAR_BLOCKS,
-    FinePlan,
-    MonoCPlan,
-    OuterPlan,
-    RowwisePlan,
-)
+from repro.distributed.plan_ir import SCALAR_BLOCKS, FinePlan, MonoCPlan, RowwisePlan
 
 
 def _take0(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -160,9 +154,19 @@ def make_rowwise_step(plan: RowwisePlan, mesh: Mesh, K: int, J: int, axis: str =
     return shard, tables
 
 
-def _dense_call_1d(plan, a_dense, b_dense, mesh: Mesh, axis: str) -> jnp.ndarray:
-    """Shared dense entry for the 1D executors: derive structures, hit the
-    runtime cache, and feed the nonzero values through the AOT executable."""
+def rowwise_spgemm(
+    a_dense: np.ndarray,
+    b_dense: np.ndarray,
+    plan: RowwisePlan,
+    mesh: Mesh,
+    axis: str = "x",
+) -> jnp.ndarray:
+    """Sparsity-dependent 1D row-wise SpGEMM.  Returns C rows in plan order
+    (device-major: C[d, r] = row ``plan.local_rows[d, r]``).
+
+    Thin wrapper over the compile-once runtime: repeated calls with the same
+    sparsity structure hit the cached AOT executable.
+    """
     from repro.distributed.runtime import compile_spgemm
     from repro.sparse.structure import from_dense
 
@@ -182,115 +186,12 @@ def _dense_call_1d(plan, a_dense, b_dense, mesh: Mesh, axis: str) -> jnp.ndarray
     return exe(a_dense[ar, ac], b_dense[br, bc])
 
 
-def rowwise_spgemm(
-    a_dense: np.ndarray,
-    b_dense: np.ndarray,
-    plan: RowwisePlan,
-    mesh: Mesh,
-    axis: str = "x",
-) -> jnp.ndarray:
-    """Sparsity-dependent 1D row-wise SpGEMM.  Returns C rows in plan order
-    (device-major: C[d, r] = row ``plan.local_rows[d, r]``).
-
-    Thin wrapper over the compile-once runtime: repeated calls with the same
-    sparsity structure hit the cached AOT executable.
-    """
-    return _dense_call_1d(plan, a_dense, b_dense, mesh, axis)
-
-
 def unpack_rowwise_result(c_local: jnp.ndarray, plan: RowwisePlan, I: int) -> np.ndarray:
     c_np = np.asarray(c_local)
     out = np.zeros((I, c_np.shape[-1]), dtype=c_np.dtype)
     dev, slot = np.nonzero(plan.local_rows >= 0)
     out[plan.local_rows[dev, slot]] = c_np[dev, slot]
     return out
-
-
-# ---------------------------------------------------------------------------
-# 1D outer-product (Ex. 5.2)
-# ---------------------------------------------------------------------------
-def make_outer_step(plan: OuterPlan, mesh: Mesh, I: int, J: int, axis: str = "x"):
-    """Jit-compatible outer-product executor core.
-
-    Returns ``(fn, ())``: ``fn(a_cols, b_rows) -> c_shards`` over
-    device-major packed operand tables (``a_cols``: (p, I, K_max);
-    ``b_rows``: (p, K_max, J)); the plan adds no tables.
-    """
-    p = plan.p
-    I_pad = (I + p - 1) // p * p
-
-    def step(a_blk, b_blk):
-        # a_blk: (1, I, K_max); b_blk: (1, K_max, J)
-        partial_c = a_blk[0] @ b_blk[0]  # (I, J) partial sum
-        partial_c = jnp.pad(partial_c, ((0, I_pad - I), (0, 0)))
-        # fold phase: reduce-scatter C row blocks
-        mine = jax.lax.psum_scatter(
-            partial_c.reshape(p, I_pad // p, J), axis, scatter_dimension=0, tiled=False
-        )
-        return mine[None]
-
-    shard = shard_map(
-        step,
-        mesh=mesh,
-        in_specs=(P(axis), P(axis)),
-        out_specs=P(axis),
-    )
-    return shard, ()
-
-
-def outer_product_spgemm(
-    a_dense: np.ndarray,
-    b_dense: np.ndarray,
-    plan: OuterPlan,
-    mesh: Mesh,
-    axis: str = "x",
-) -> jnp.ndarray:
-    """1D outer-product SpGEMM: device d computes sum_{k in K_d} a_:k b_k:,
-    fold phase reduces partial C over devices, scattering C row blocks.
-
-    Returns C sharded by row blocks of size ceil(I/p) (device-major).  Thin
-    wrapper over the compile-once runtime (see ``rowwise_spgemm``).
-    """
-    return _dense_call_1d(plan, a_dense, b_dense, mesh, axis)
-
-
-def spsumma(
-    a_dense: np.ndarray,
-    b_dense: np.ndarray,
-    mesh: Mesh,
-    axes: tuple[str, str] = ("x", "y"),
-) -> jnp.ndarray:
-    """Sparse SUMMA (2D, stationary C): K-step loop broadcasting A panels
-    along mesh rows and B panels along mesh columns via collective permutes
-    (systolic variant — bandwidth-equivalent to broadcast SUMMA)."""
-    ax_r, ax_c = axes
-    pr, pc = mesh.shape[ax_r], mesh.shape[ax_c]
-    I, K = a_dense.shape
-    _, J = b_dense.shape
-    I_p = (I + pr - 1) // pr * pr
-    K_p = (K + pr * pc - 1) // (pr * pc) * (pr * pc)
-    J_p = (J + pc - 1) // pc * pc
-    a_pad = np.zeros((I_p, K_p), a_dense.dtype)
-    a_pad[:I, :K] = a_dense
-    b_pad = np.zeros((K_p, J_p), b_dense.dtype)
-    b_pad[:K, :J] = b_dense
-
-    def step(a_blk, b_blk):
-        # a_blk: (I_p/pr, K_p/pc); b_blk: (K_p/pr, J_p/pc)
-        # Cannon-style: skew, then pr*pc rotate-multiply steps over the K axis
-        # Simpler: all_gather panels (volume identical to SUMMA broadcasts).
-        a_row = jax.lax.all_gather(a_blk, ax_c, axis=1, tiled=True)  # (I/pr, K_p)
-        b_col = jax.lax.all_gather(b_blk, ax_r, axis=0, tiled=True)  # (K_p, J/pc)
-        return a_row @ b_col
-
-    shard = shard_map(
-        step,
-        mesh=mesh,
-        in_specs=(P(ax_r, ax_c), P(ax_r, ax_c)),
-        out_specs=P(ax_r, ax_c),
-    )
-    out = shard(jnp.asarray(a_pad), jnp.asarray(b_pad))
-    return out[:I, :J]
 
 
 # ---------------------------------------------------------------------------

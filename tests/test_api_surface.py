@@ -131,6 +131,18 @@ def test_loop_reference_demoted_but_shimmed():
         assert dist.build_rowwise_plan_loop is plan_mod.build_rowwise_plan_loop
 
 
+def test_distributed_exposes_no_deleted_name():
+    """Columnwise and outer run on the fine executor: outer's plan class,
+    builder and dense wrapper and the superseded SpSUMMA are gone from the
+    package, with no shim left behind."""
+    import repro.distributed as dist
+
+    for name in ("OuterPlan", "build_outer_plan", "outer_product_spgemm", "spsumma"):
+        assert name not in dist.__all__, name
+        with pytest.raises(AttributeError):
+            getattr(dist, name)
+
+
 def test_distributed_all_lists_only_supported_entry_points():
     import repro.distributed as dist
 

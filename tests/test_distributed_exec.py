@@ -27,7 +27,7 @@ def _run(case: str, devices: int = 4) -> str:
 
 
 @pytest.mark.parametrize(
-    "case", ["rowwise", "outer", "spsumma", "rowwise_identity_partition"]
+    "case", ["rowwise", "outer", "columnwise", "rowwise_identity_partition"]
 )
 def test_distributed_spgemm(case):
     assert f"OK {case.split('_partition')[0]}" in _run(case)
@@ -59,7 +59,7 @@ def test_fine_identity_partition_has_zero_traffic():
 @pytest.mark.parametrize("devices", [4, 8])
 def test_model_selection_sweep_end_to_end(devices):
     """sweep_instance: all models partitioned, executors run, and measured
-    == predicted words for the replicated-free (fine, monoC) plans."""
+    == predicted words for every plan."""
     assert "OK select best=" in _run("select", devices=devices)
 
 

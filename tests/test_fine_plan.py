@@ -186,7 +186,7 @@ def test_fine_plan_host_simulation_reproduces_dense():
 # ---------------------------------------------------------------------------
 # executable plans through the selection pipeline
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("model", ["rowwise", "outer", "monoC", "fine"])
+@pytest.mark.parametrize("model", ["rowwise", "columnwise", "outer", "monoC", "fine"])
 def test_executable_plan_measures_its_models_prediction(model):
     """Pin-derived ownership makes each executable plan's table-counted
     words equal the model's connectivity prediction."""
@@ -212,3 +212,39 @@ def test_sweep_instance_selects_min_predicted():
     assert best["selected"] and sum(r["selected"] for r in recs) == 1
     for r in ok:
         assert r["volume_plan_words"] == r["predicted_words"]
+
+
+def test_store_entry_of_a_deleted_plan_class_is_replanned_as_fine(tmp_path):
+    """A store entry written when outer had its own plan class
+    (``plan_class: "OuterPlan"``) is quarantined as an unknown class, and
+    the session replans the structure as a fine-family plan."""
+    import json
+    import os
+
+    import repro
+    from repro.checkpoint import list_plans, restore_plan
+    from repro.distributed.plan_ir import FinePlan
+    from repro.resilience import FaultPolicy
+
+    rng = np.random.default_rng(12)
+    A = (rng.random((14, 12)) * (rng.random((14, 12)) < 0.35)).astype(np.float32)
+    B = (rng.random((12, 13)) * (rng.random((12, 13)) < 0.35)).astype(np.float32)
+    store = str(tmp_path / "store")
+    policy = FaultPolicy(max_retries=2, backoff_s=0.0)
+    repro.session(p=1, model="outer", policy=policy, store_dir=store).multiply(A, B)
+    (key,) = list_plans(store)
+    manifest_path = os.path.join(store, key, "manifest.json")
+    with open(manifest_path) as f:
+        manifest = json.load(f)
+    manifest["plan_class"] = "OuterPlan"
+    with open(manifest_path, "w") as f:
+        json.dump(manifest, f)
+
+    s = repro.session(p=1, model="outer", policy=policy, store_dir=store)
+    with pytest.warns(RuntimeWarning, match="quarantin"):
+        C = np.asarray(s.multiply(A, B))
+    np.testing.assert_allclose(C, A @ B, rtol=2e-4, atol=2e-4)
+    assert [e.kind for e in s.events][:1] == ["cold_replan"]
+    assert any(d.startswith(key + ".quarantined") for d in os.listdir(store))
+    back = restore_plan(store, key)
+    assert type(back.plan) is FinePlan and back.plan.model == "outer"

@@ -4,15 +4,12 @@ routes — no multi-device jax needed."""
 import numpy as np
 import pytest
 
-from repro.core import SpGEMMInstance
+from repro.core import SpGEMMInstance, build_model, evaluate
 from repro.core.spgemm_models import _lin_lookup
-from repro.distributed import (
-    build_monoC_plan,
-    build_outer_plan,
-    build_rowwise_plan,
-)
+from repro.distributed import build_rowwise_plan
 from repro.distributed.plan import build_rowwise_plan_loop
 from repro.distributed.plan_ir import padded_id_lists, plan_monoC_from_dense
+from repro.distributed.registry import get_spec
 from repro.kernels.bsr_spgemm import build_pair_lists, build_pair_lists_loop
 from repro.sparse.structure import random_structure
 
@@ -96,14 +93,18 @@ def test_route_accounting_and_membership():
 
 
 def test_outer_plan_fold_volume_via_stats():
+    """Outer keeps A column k and B row k on k's part, so only C partials
+    move (the fold), and their words are the outer hypergraph's
+    connectivity."""
     inst = _instance(4)
     rng = np.random.default_rng(4)
     p = 4
-    plan = build_outer_plan(inst, rng.integers(0, p, inst.shape[1]), p)
-    assert plan.routes == {}
-    assert plan.comm_words_ideal == plan.stats["fold_words_ideal"] >= 0
-    # the dense psum_scatter fold dominates the connectivity metric, so the
-    # model-agnostic padding invariant holds for route-less plans too
+    k_part = rng.integers(0, p, inst.shape[1])
+    plan = get_spec("outer").lower(inst, k_part, p)
+    moving = {name for name, r in plan.routes.items() if r.items_ideal}
+    assert moving == {"reduce_c"}
+    hg = build_model(inst, "outer")
+    assert plan.comm_words_ideal == evaluate(hg, k_part, p).connectivity > 0
     assert plan.comm_words_padded >= plan.comm_words_ideal
     assert 0.0 <= plan.padding_fraction <= 1.0
 

@@ -2,10 +2,9 @@
 core model list, and the repro.api round-trip oracle.
 
 Completeness is the load-bearing property: every name in ``MODELS`` must
-either carry a *full* executable spec (lowerer + runner + unpacker + mesh)
-or be *explicitly* marked volume-only — a half-wired entry (e.g. a lowerer
-without an executor) is exactly the kind of drift the old three-site
-dispatch allowed, and is an error here.
+carry a *full* executable spec (lowerer + runner + unpacker + mesh) — a
+half-wired entry (e.g. a lowerer without an executor) is exactly the kind
+of drift the old three-site dispatch allowed, and is an error here.
 
 The p=1 oracle runs in-process (a 1-device mesh exercises the full packed
 program); p in {4, 8} goes through the subprocess runner so forced host
@@ -21,7 +20,10 @@ import pytest
 from repro.core.spgemm_models import MODELS, SpGEMMInstance
 from repro.distributed.registry import (
     MODEL_SPECS,
-    VOLUME_ONLY,
+    _fine_runner,
+    _monoC_runner,
+    _rowwise_runner,
+    _summa_runner,
     executable_models,
     get_spec,
 )
@@ -72,8 +74,17 @@ def test_every_model_fully_executable(model):
     assert all(callable(f) for f in parts), f"{model}: partial spec"
     assert callable(spec.mesh_shape) and spec.axis_names
     assert spec.measured in ("exact", "useful")
-    assert model not in VOLUME_ONLY
-    assert VOLUME_ONLY == ()
+
+
+def test_fine_runner_runs_every_model_but_rowwise_monoC_and_summa2d():
+    """Columnwise, outer, monoA and monoB are fine plans, so four runners
+    serve all eight entries."""
+    runners = {name: spec.make_runner for name, spec in MODEL_SPECS.items()}
+    others = {n for n, r in runners.items() if r is not _fine_runner}
+    assert others == {"rowwise", "monoC", "summa2d"}
+    assert runners["rowwise"] is _rowwise_runner
+    assert runners["monoC"] is _monoC_runner and runners["summa2d"] is _summa_runner
+    assert len(set(runners.values())) == 4
 
 
 def test_executable_models_matches_select_surface():
@@ -154,9 +165,7 @@ def test_plan_auto_selects_min_predicted_words():
 
 def test_cost_report_planned_equals_predicted_for_every_model():
     """The front door exposes the paper's predicted == planned identity:
-    exact for replicated-free plans, via item weighting for rowwise, via
-    fold accounting for outer, and through the volume plan for the
-    volume-only models."""
+    exact for replicated-free plans and via item weighting for rowwise."""
     import repro
 
     rng = np.random.default_rng(6)

@@ -5,7 +5,8 @@ import numpy as np
 import pytest
 
 from repro.core import SpGEMMInstance, build_model, evaluate, partition
-from repro.distributed import build_outer_plan, build_rowwise_plan
+from repro.distributed import build_rowwise_plan, measured_route_words
+from repro.distributed.registry import get_spec
 from repro.sparse.structure import random_structure
 
 
@@ -46,10 +47,11 @@ def test_outer_model_matches_fold_plan(seed):
     p = 4
     hg = build_model(inst, "outer")
     res = partition(hg, p, eps=0.3, seed=seed)
-    plan = build_outer_plan(inst, res.parts[: inst.shape[1]], p)
+    plan = get_spec("outer").lower(inst, res.parts, p)
     costs = evaluate(hg, res.parts, p)
-    # outer model nets are C nonzeros with unit cost; connectivity = fold
-    assert costs.connectivity == plan.comm_words_ideal
+    # outer model nets are C nonzeros with unit cost; connectivity = the
+    # C partials the reduce route ships
+    assert costs.connectivity == measured_route_words(plan) == plan.comm_words_ideal
 
 
 def test_partition_quality_transfers_to_executor(tmp_path):
