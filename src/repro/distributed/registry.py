@@ -62,15 +62,18 @@ class RunnerSetup:
     """What the compile-once runtime needs to AOT-compile one executor:
     a jit-compatible ``run(a_values, b_values, *tables) -> c_shards``
     function, the value shapes it was built for, the dense shape ``unpack``
-    recovers, and ``tables``: the plan's integer index arrays (scatter
-    indices, routes, pair lists) as host arrays.  The runtime uploads the
-    tables once and passes them as device arguments on every call."""
+    recovers, and ``tables``: the plan's integer index arrays (id lists,
+    routes, pair lists) as host arrays.  The runtime uploads the tables
+    once and passes them as device arguments on every call.  ``owned``
+    says how each operand's owned table is built, ``"in_place"`` or
+    ``"gathered"`` (``spgemm_exec.owned_values``); the runtime counts it."""
 
     run: Callable
     a_shape: tuple[int, ...]
     b_shape: tuple[int, ...]
     out_shape: tuple[int, int]
     tables: tuple = ()
+    owned: tuple[str, ...] = ()
 
 
 def vmap_batched_runner(make_runner: Callable) -> Callable:
@@ -90,13 +93,12 @@ def vmap_batched_runner(make_runner: Callable) -> Callable:
         import jax
 
         setup = make_runner(plan, a_structure, b_structure, mesh, **kwargs)
-        return RunnerSetup(
+        return dataclasses.replace(
+            setup,
             # values map over the batch axis; the tables are shared
             run=jax.vmap(setup.run, in_axes=(0, 0) + (None,) * len(setup.tables)),
             a_shape=(batch, *setup.a_shape),
             b_shape=(batch, *setup.b_shape),
-            out_shape=setup.out_shape,
-            tables=setup.tables,
         )
 
     return make_batched
@@ -221,85 +223,59 @@ def _rowwise_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backe
 
 
 def owned_nz_setup(
-    plan, a_structure, b_structure, step, step_tables, *, dtype, item_shape=(), out_shape
+    plan, a_structure, b_structure, step, step_tables, mesh, axis, *, item_shape=(), out_shape
 ) -> RunnerSetup:
     """Runner for executors whose operands live in owned nonzero slots
-    (fine, monoA, monoB, monoC, summa2d): scatter the value vectors into
-    device-major ``(p, N_max, *item_shape)`` tables (device scope
-    ``repro.scatter_values``), then run ``step``."""
-    import jax
-    import jax.numpy as jnp
+    (fine, monoA, monoB, monoC, summa2d): build the device-major
+    ``(p, N_max, *item_shape)`` tables from the value vectors through the
+    plan's id lists (``spgemm_exec.owned_values``: the vector itself where
+    the list is the caller's order, else each device gathers its own ids),
+    then run ``step``."""
+    from repro.distributed.spgemm_exec import owned_values
 
-    p = plan.p
     nA, nB = a_structure.nnz, b_structure.nnz
-    if nA != len(plan.a_part) or nB != len(plan.b_part):
-        raise ValueError("plan was built for a different nonzero structure")
-    adev, aslot = owner_slot(plan.local_ids["a_nz"], nA)
-    bdev, bslot = owner_slot(plan.local_ids["b_nz"], nB)
-    N_a = plan.local_ids["a_nz"].shape[1]
-    N_b = plan.local_ids["b_nz"].shape[1]
+    ways, id_tables, own = owned_values(plan, nA, nB, mesh, axis)
 
-    def run(a_values, b_values, a_d, a_s, b_d, b_s, *tables):
-        with jax.named_scope("repro.scatter_values"):
-            a_own = jnp.zeros((p, N_a, *item_shape), dtype).at[a_d, a_s].set(a_values)
-            b_own = jnp.zeros((p, N_b, *item_shape), dtype).at[b_d, b_s].set(b_values)
-        return step(a_own, b_own, *tables)
+    def run(a_values, b_values, *tables):
+        a_own, b_own, rest = own(a_values, b_values, *tables)
+        return step(a_own, b_own, *rest)
 
     return RunnerSetup(
-        run,
-        (nA, *item_shape),
-        (nB, *item_shape),
-        out_shape,
-        (adev, aslot, bdev, bslot, *step_tables),
+        run, (nA, *item_shape), (nB, *item_shape), out_shape, (*id_tables, *step_tables), ways
     )
 
 
 def block_nz_setup(
-    plan, a_structure, b_structure, step, step_tables, *, dtype, out_shape
+    plan, a_structure, b_structure, step, step_tables, mesh, axis, *, out_shape
 ) -> RunnerSetup:
     """Runner for the fine family over block operands (``plan.blocks`` =
     ((r, k), (k, c))).  Values arrive flat in block CSR order, (nnz*r*k,)
     and (nnz*k*c,), block after block: a host array of (nnz, 3, 3) blocks
     would be padded to the TPU's (8, 128) tiles in its last two dimensions
     on the way in.  On the device (scope ``repro.block_layout``) they turn
-    items-major, (r*k, nnz); the scatter into the owned tables
-    (``repro.scatter_values``) moves whole columns; and the step's
-    items-major C, (p, r*c, slots), goes back flat and block-major,
+    items-major, (r*k, nnz); the owned tables (``spgemm_exec.owned_values``)
+    are those columns in place or gathered through the id lists; and the
+    step's items-major C, (p, r*c, slots), goes back flat and block-major,
     (p*slots*r*c,), for ``spgemm_exec.owned_c_values``."""
     import jax
-    import jax.numpy as jnp
 
-    p = plan.p
+    from repro.distributed.spgemm_exec import owned_values
+
     nA, nB = a_structure.nnz, b_structure.nnz
-    if nA != len(plan.a_part) or nB != len(plan.b_part):
-        raise ValueError("plan was built for a different nonzero structure")
     items_a, items_b, _ = block_areas(plan.blocks)
-    adev, aslot = owner_slot(plan.local_ids["a_nz"], nA)
-    bdev, bslot = owner_slot(plan.local_ids["b_nz"], nB)
-    N_a = plan.local_ids["a_nz"].shape[1]
-    N_b = plan.local_ids["b_nz"].shape[1]
+    ways, id_tables, own = owned_values(plan, nA, nB, mesh, axis, columns=True)
 
-    def owned(values, n, items, n_max, col):
+    def run(a_values, b_values, *tables):
         with jax.named_scope("repro.block_layout"):
-            by_item = values.reshape(n, items).T
-        with jax.named_scope("repro.scatter_values"):
-            tab = jnp.zeros((items, p * n_max), dtype).at[:, col].set(by_item)
-            return tab.reshape(items, p, n_max).transpose(1, 0, 2)
-
-    def run(a_values, b_values, a_col, b_col, *tables):
-        a_own = owned(a_values, nA, items_a, N_a, a_col)
-        b_own = owned(b_values, nB, items_b, N_b, b_col)
-        c = step(a_own, b_own, *tables)
+            a_items = a_values.reshape(nA, items_a).T
+            b_items = b_values.reshape(nB, items_b).T
+        a_own, b_own, rest = own(a_items, b_items, *tables)
+        c = step(a_own, b_own, *rest)
         with jax.named_scope("repro.block_layout"):
             return c.transpose(0, 2, 1).reshape(-1)
 
     return RunnerSetup(
-        run,
-        (nA * items_a,),
-        (nB * items_b,),
-        out_shape,
-        # each nonzero's column in the (items, p * N_max) scatter target
-        (adev * N_a + aslot, bdev * N_b + bslot, *step_tables),
+        run, (nA * items_a,), (nB * items_b,), out_shape, (*id_tables, *step_tables), ways
     )
 
 
@@ -311,11 +287,11 @@ def _fine_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend,
         (r, _), (_, c) = plan.blocks
         out_shape = (a_structure.shape[0] * r, b_structure.shape[1] * c)
         return block_nz_setup(
-            plan, a_structure, b_structure, step, tables, dtype=dtype, out_shape=out_shape
+            plan, a_structure, b_structure, step, tables, mesh, axis, out_shape=out_shape
         )
     out_shape = (a_structure.shape[0], b_structure.shape[1])
     return owned_nz_setup(
-        plan, a_structure, b_structure, step, tables, dtype=dtype, out_shape=out_shape
+        plan, a_structure, b_structure, step, tables, mesh, axis, out_shape=out_shape
     )
 
 
@@ -331,7 +307,8 @@ def _monoC_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend
         b_structure,
         step,
         tables,
-        dtype=dtype,
+        mesh,
+        axes,
         item_shape=(block, block),
         out_shape=(a_structure.shape[0] * block, b_structure.shape[1] * block),
     )

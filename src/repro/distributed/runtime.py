@@ -13,13 +13,13 @@ so nothing caches).
 ``CompiledSpGEMM`` does all structure-time work exactly once per
 (plan, operand structure, mesh, dtype, backend):
 
-- host packing collapses to one vectorized owner/slot scatter-spec (the
-  ``np.nonzero(local_ids >= 0)`` idiom), computed at construction;
-- route tables, pair lists and scatter indices are uploaded to the devices
+- host packing collapses to the plan's id lists, read at construction
+  (none where an operand's list is the caller's own order);
+- route tables, pair lists and id lists are uploaded to the devices
   once, with the shardings the compiled program asks for, and passed as
   arguments on every call (baked in as constants, they made compile time
   and the executable grow with the plan);
-- the whole executor (value scatter -> expand -> local compute -> reduce)
+- the whole executor (owned values -> expand -> local compute -> reduce)
   is AOT-compiled via ``jax.jit(...).lower().compile()`` with the value
   buffers donated, so ``__call__(a_values, b_values)`` does zero host
   structure work and zero retracing — the steady-state cost is exactly the
@@ -148,11 +148,12 @@ def _mesh_key(mesh: Mesh) -> tuple:
 class CompiledSpGEMM:
     """One AOT-compiled SpGEMM executor: structure work done, values only.
 
-    Construction performs every structure-dependent step (scatter-spec
-    build, constant upload, trace, lowering, XLA compile) by handing the
-    plan's ``ModelSpec.make_runner`` the operand structures; ``__call__``
-    takes nonzero value vectors and returns the executor's device-major
-    C shards with no host structure work and no retracing.
+    Construction performs every structure-dependent step (how each
+    operand's owned table is built, table upload, trace, lowering, XLA
+    compile) by handing the plan's ``ModelSpec.make_runner`` the operand
+    structures; ``__call__`` takes nonzero value vectors and returns the
+    executor's device-major C shards with no host structure work and no
+    retracing.
     """
 
     def __init__(
@@ -237,6 +238,8 @@ class CompiledSpGEMM:
         self._tables = tuple(
             jax.device_put(t, sh) for t, sh in zip(tables, in_shardings)
         )
+        for way in setup.owned:
+            _STATS[f"values_{way}"] += 1
 
     def _coerce(self, x, shape, name: str):
         if isinstance(x, jax.Array):
@@ -294,7 +297,9 @@ class CompiledSpGEMM:
 # -- bounded LRU cache -------------------------------------------------------
 CACHE_SIZE = int(os.environ.get("REPRO_EXEC_CACHE_SIZE", "16"))
 _CACHE: OrderedDict[tuple, CompiledSpGEMM] = OrderedDict()
-_STATS = {"hits": 0, "misses": 0}
+#: cache hits and misses, and the compiled executors' operands by how each
+#: built its owned table (``registry.RunnerSetup.owned``)
+_STATS = {"hits": 0, "misses": 0, "values_in_place": 0, "values_gathered": 0}
 
 
 def _cache_key(
@@ -372,4 +377,4 @@ def cache_info() -> dict:
 
 def cache_clear() -> None:
     _CACHE.clear()
-    _STATS["hits"] = _STATS["misses"] = 0
+    _STATS.update(dict.fromkeys(_STATS, 0))

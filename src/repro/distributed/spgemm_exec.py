@@ -41,7 +41,7 @@ tables and work lists, which the function takes as arguments (the runtime
 uploads them to the devices once; baked in as constants they made compile
 time grow with the plan).  The dense entry points
 below are thin wrappers over ``repro.distributed.runtime.compile_spgemm``,
-which scatters nonzero value vectors into the packed layout *inside* the
+which reads nonzero value vectors into the packed layout *inside* the
 compiled program and AOT-compiles the whole executor once per
 (plan, structure, mesh, dtype, backend) — repeated same-structure calls pay
 no host packing, no route re-upload and no retracing.  Correctness oracle:
@@ -95,11 +95,56 @@ def _segment_heads(vals: jnp.ndarray, seg: jnp.ndarray, n_pass: int) -> jnp.ndar
 def _own_tables(a_blk: jnp.ndarray, b_blk: jnp.ndarray):
     """This device's owned value tables out of its (1, N_max, ...) blocks.
 
-    The runner's value scatter (``registry.owned_nz_setup``) fuses into
+    The runner's gather of the owned values (``owned_values``) fuses into
     this squeeze, and a fused operation takes its scope from its last
-    step, so the squeeze carries the scatter's scope."""
+    step, so the squeeze carries the gather's scope."""
     with jax.named_scope("repro.scatter_values"):
         return a_blk[0], b_blk[0]
+
+
+def owned_values(plan, n_a: int, n_b: int, mesh: Mesh, axis, *, columns: bool = False):
+    """How the device-major owned tables are built from the caller's value
+    vectors, decided from the plan's id lists ``local_ids["a_nz"]`` and
+    ``["b_nz"]``, for A and B separately.
+
+    Returns ``(ways, tables, own)``.  An operand whose id list is the
+    caller's own order, one device owning ids 0..n-1 in turn (every plan
+    built at p=1), takes its vector as the table, with a leading device
+    axis: way ``"in_place"``, no data movement and no table.  Any other
+    reads the vector through its id list: each device gathers its own row
+    of ids, ``N_max`` of them (0 where an id is -1), out of the replicated
+    vector under the device scope ``repro.scatter_values``: way
+    ``"gathered"``, and the id list is its table.  ``own(a, b, *tables)``
+    gives ``(a_own, b_own, rest)``, ``rest`` being what follows the id
+    lists in ``tables``.  ``columns``: the vectors are items-major
+    (items, n) and the ids name columns.
+    """
+    if n_a != len(plan.a_part) or n_b != len(plan.b_part):
+        raise ValueError("plan was built for a different nonzero structure")
+    take = _take_items if columns else _take0
+
+    def gather(values, ids):
+        with jax.named_scope("repro.scatter_values"):
+            return take(values, ids[0])[None]
+
+    gathered = shard_map(gather, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis))
+    ways, tables = [], []
+    for ids, n in ((plan.local_ids["a_nz"], n_a), (plan.local_ids["b_nz"], n_b)):
+        in_place = ids.shape == (1, n) and np.array_equal(ids[0], np.arange(n))
+        ways.append("in_place" if in_place else "gathered")
+        tables += [] if in_place else [ids]
+
+    def own(a, b, *tables):
+        out = []
+        for way, values in zip(ways, (a, b)):
+            if way == "in_place":
+                out.append(values[None])
+            else:
+                out.append(gathered(values, tables[0]))
+                tables = tables[1:]
+        return (*out, tables)
+
+    return tuple(ways), tuple(tables), own
 
 
 # ---------------------------------------------------------------------------

@@ -314,7 +314,7 @@ def make_summa_step(
 
 def _summa_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend, axis, axes):
     """Registry runner factory (monoC value layout: ``(nnz, b, b)`` blocks
-    scattered into device-major owned tables)."""
+    read into device-major owned tables)."""
     from repro.distributed.registry import owned_nz_setup
 
     step, tables = make_summa_step(plan, mesh, block=block, backend=backend, axes=axes)
@@ -324,7 +324,8 @@ def _summa_runner(plan, a_structure, b_structure, mesh, *, dtype, block, backend
         b_structure,
         step,
         tables,
-        dtype=dtype,
+        mesh,
+        axes,
         item_shape=(block, block),
         out_shape=(a_structure.shape[0] * block, b_structure.shape[1] * block),
     )

@@ -5,11 +5,9 @@ Checked against ``np.add.at`` on sorted segment ids, against the step as it
 was before (scatter-add into a produced table, then a scatter-add fold
 through ``prod_to_owned``, kept below as ``scatter_step``) and against
 scipy's float64 product; and, in the compiled p=1 program, that no scatter
-is left under ``repro.local``.
+is left under ``repro.local`` or ``repro.scatter_values``.
 """
 from __future__ import annotations
-
-import re
 
 import numpy as np
 import pytest
@@ -20,7 +18,7 @@ from repro.core.matrices import amg_instances
 from repro.distributed.plan_ir import SCALAR_BLOCKS, build_fine_plan
 from repro.distributed.spgemm_exec import _segment_heads, owned_c_values
 from repro.sparse.structure import from_dense, random_structure
-from test_named_scopes import INSTR, SCOPE
+from test_named_scopes import ops_by_scope
 
 ELASTIC = ((3, 3), (3, 6))
 TOL = 1e-6
@@ -165,24 +163,13 @@ def test_one_device_step_matches_the_scatter_step_and_scipy(amg6, model, blocks)
     assert (np.abs(got - at_c(a64 @ b64)) <= TOL * scale).all()
 
 
-def scatters_by_scope(hlo: str) -> dict[str, int]:
-    """Scatter instructions of every computation (fused ones too), counted
-    by the program scope of their ``op_name``."""
-    out: dict[str, int] = {}
-    for line in hlo.splitlines():
-        m = INSTR.match(line)
-        if not m or m["op"] != "scatter":
-            continue
-        md = re.search(r'op_name="([^"]*)"', line)
-        for scope in set(SCOPE.findall(md.group(1))) if md else {""}:
-            out[scope] = out.get(scope, 0) + 1
-    return out
-
-
 @pytest.mark.parametrize("blocks", [SCALAR_BLOCKS, ELASTIC], ids=["scalar", "3x3.3x6"])
 def test_one_device_fine_program_has_no_scatter_under_local(amg6, blocks):
+    """No scatter under ``repro.local``, and none under
+    ``repro.scatter_values``: at p=1 the owned tables are the value
+    vectors themselves."""
     exe = repro.plan(*amg6, p=1, model="fine", blocks=blocks).compile(dtype=np.float32)
     hlo = exe.runtime._compiled.as_text()
-    scatters = scatters_by_scope(hlo)
-    assert scatters.get("repro.scatter_values", 0) > 0  # the value scatter is read
+    scatters = ops_by_scope(hlo, ("scatter",))
     assert "repro.local" in hlo and "repro.local" not in scatters, scatters
+    assert "repro.scatter_values" not in scatters, scatters

@@ -22,6 +22,7 @@ import repro
 from repro.core.matrices import amg_instances
 from repro.kernels import bsr_spgemm as bsr_spgemm_mod
 from repro.kernels.bsr_spmm import bsr_spmm
+from test_named_scopes import INSTR, ops_by_scope
 
 
 @pytest.fixture(scope="module")
@@ -145,10 +146,37 @@ def test_blocked_fine_step_compiles_for_four_tpus(topo, amg_small):
     assert "all-to-all" in compiled.as_text()
 
 
+@pytest.mark.parametrize("model", ["fine", "monoC"])
+def test_four_tpus_each_gather_only_their_own_ids(topo, amg_small, model):
+    """The whole four-chip program, values replicated: under
+    ``repro.scatter_values`` each chip gathers its own row of A's and of
+    B's id lists, N_max values each, not the p * N_max of every chip."""
+    from repro.distributed.registry import get_spec
+
+    plan = repro.plan(amg_small, p=4, model=model).execution_plan
+    spec = get_spec(model)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(spec.mesh_shape(4)), spec.axis_names)
+    setup = spec.make_setup(plan, amg_small.a, amg_small.b, mesh, dtype=np.float32, block=1,
+                            backend="xla", axis="x", axes=("x", "y"))
+    assert setup.owned == ("gathered", "gathered")
+    replicated = NamedSharding(mesh, P())
+    text = jax.jit(setup.run).lower(
+        _sds(setup.a_shape, jnp.float32, replicated),
+        _sds(setup.b_shape, jnp.float32, replicated),
+        *(jax.ShapeDtypeStruct(np.shape(t), jnp.int32) for t in setup.tables),
+    ).compile().as_text()
+    rows = sorted(
+        int(re.search(r"= \w+\[(\d+)", line)[1]) for line in text.splitlines()
+        if (m := INSTR.match(line)) and m["op"] == "gather" and "repro.scatter_values" in line
+    )
+    n_max = sorted(plan.local_ids[k].shape[1] for k in ("a_nz", "b_nz"))
+    assert rows == n_max, (rows, n_max)
+
+
 @pytest.fixture(scope="module")
 def elastic72(one_chip):
-    """The 72^3 elasticity cell's whole program (layout change, value
-    scatter, step) at its real shapes, compiled for one v5e.  Only the
+    """The 72^3 elasticity cell's whole program (layout change, owned
+    tables, step) at its real shapes, compiled for one v5e.  Only the
     shapes of the p=1 plan's tables and its run structure matter, so the
     plan is built from them: each of the n_c produced slots a run of 9 to
     27 block products (the 27-point stencil's longest is 27, so the step
@@ -218,3 +246,10 @@ def test_segment_sum_reads_no_view_of_a_buffer_it_overwrites(elastic72):
         if ops.get(arg, ("",))[0] == "bitcast" and ops[arg][1][0] in args
     }
     assert not viewing, viewing
+
+
+def test_elastic72_moves_no_value_into_its_owned_tables(elastic72):
+    """At p=1 the owned tables are the items-major value vectors: no gather
+    and no scatter under ``repro.scatter_values``."""
+    moved = ops_by_scope(elastic72.as_text(), ("gather", "scatter"))
+    assert "repro.scatter_values" not in moved, moved
